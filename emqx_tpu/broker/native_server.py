@@ -10,7 +10,7 @@ to this driver, which runs the same ``Channel`` FSM the asyncio server
 uses. This is SURVEY.md §7's "host side in C++" design: the reference
 runs its hot loop in per-connection BEAM processes
 (emqx_connection.erl:403-440 → emqx_broker.erl:218-232); the GIL makes
-that shape a ~14k msg/s ceiling in Python (BENCH_r03), so the hot loop
+that shape a ~14k msg/s ceiling in Python (round-3 CPU bench), so the hot loop
 moves below the GIL instead.
 
 Correctness seams (all of them fail toward the slow path, which is
@@ -896,7 +896,12 @@ class NativeBrokerServer:
         # size (LANE_AUTO_* hysteresis, judged each housekeep) because
         # the per-message C++ walk wins below the crossover point.
         self.device_lane = device_lane if fast_path else "off"
-        self._lane_on = False
+        self._lane_on = False   # wanted on; written under _lane_lock
+        # set once the pump has compiled every lane program for the
+        # current tables and the C++ side parks frames again
+        self.lane_open = threading.Event()
+        self.lane_compile_s: dict[str, float] = {}   # the last warm's
+        self._lane_lock = threading.Lock()
         self._lane_q: queue.SimpleQueue = queue.SimpleQueue()
         self._lane_stop = threading.Event()
         self._lane_thread: Optional[threading.Thread] = None
@@ -1262,10 +1267,19 @@ class NativeBrokerServer:
         return getattr(self.broker, "model", None)
 
     def _set_lane(self, on: bool) -> None:
-        if on == self._lane_on:
-            return
-        if on:
-            if self._lane_model() is None:
+        """Off closes the C++ lane at once (parked frames drain to
+        Python). On starts the pump, which opens the C++ lane only
+        after ``model.warm`` has compiled every program it can launch,
+        so no frame waits on a compile."""
+        with self._lane_lock:
+            if on == self._lane_on:
+                return
+            if on and self._lane_model() is None:
+                return
+            self._lane_on = on
+            if not on:
+                log.info("device lane OFF")
+                self._lane_close()
                 return
             self._lane_stop.clear()
             if self._lane_thread is None or not self._lane_thread.is_alive():
@@ -1274,10 +1288,30 @@ class NativeBrokerServer:
                     daemon=True)
                 self._lane_thread.start()
             log.info("device lane ON (filters=%s)", self._lane_filters())
-        else:
-            log.info("device lane OFF")
-        self._lane_on = on
-        self.host.set_lane(on)   # off drains parked frames to Python
+
+    def _lane_close(self) -> None:   # caller holds _lane_lock
+        self.host.set_lane(False)   # drains parked frames to Python
+        self._lane_drained()
+
+    def _lane_drained(self) -> None:   # caller holds _lane_lock
+        self.lane_open.clear()
+        # a drain revokes the drained topics' C++ permits: forget the
+        # Python record so their next slow-path publish re-grants them
+        with self._permit_lock:
+            self._granted.clear()
+
+    def _lane_warm_open(self, model) -> None:
+        """Pump side of opening: compile for the current tables, then
+        let C++ park frames — unless the lane was turned off meanwhile."""
+        seconds = model.warm(LANE_MAX_BATCH)
+        if seconds:
+            self.lane_compile_s = seconds
+            log.info("device lane compiled %d programs in %.1fs",
+                     len(seconds), sum(seconds.values()))
+        with self._lane_lock:
+            if self._lane_on and not self._lane_stop.is_set():
+                self.host.set_lane(True)
+                self.lane_open.set()
 
     def _lane_filters(self) -> int:
         model = self._lane_model()
@@ -1300,7 +1334,9 @@ class NativeBrokerServer:
             if self._lane_on:
                 log.warning("device lane stale-tripped in C++; resyncing "
                             "(retry in %ss)", LANE_STALE_BACKOFF_S)
-                self._lane_on = False   # C++ already drained + disabled
+                with self._lane_lock:   # C++ already drained + disabled
+                    self._lane_on = False
+                    self._lane_drained()
                 # a wedged device would re-trip every few seconds: the
                 # walk/Python paths are always correct, so sit out the
                 # backoff before trusting the pump again
@@ -1324,12 +1360,18 @@ class NativeBrokerServer:
         launches (up to LANE_PIPE_DEPTH in flight — the double-buffering
         that hides the device round trip), and answer C++ with the
         matched filter strings. Every failure answers 'punt' so the
-        frames take the always-correct Python path."""
+        frames take the always-correct Python path. Opening (and
+        reopening after the tables grow) compiles first: see
+        ``_lane_warm_open``."""
+        from emqx_tpu.models.router_model import ColdTables
+
         model = self._lane_model()
         pending: deque = deque()   # submitted, uncollected device batches
         inbox: deque = deque()     # (seq, topic) awaiting submission
         try:
             while not self._lane_stop.is_set():
+                if self._lane_on and not self.lane_open.is_set():
+                    self._lane_warm_open(model)
                 try:
                     items = self._lane_q.get(
                         timeout=0.0005 if (pending or inbox) else 0.05)
@@ -1354,8 +1396,17 @@ class NativeBrokerServer:
                     seqs = [(h, s) for h, s, _ in chunk]
                     topics = [t for _, _, t in chunk]
                     try:
-                        pending.append(
-                            (model.publish_batch_submit(topics), seqs))
+                        pending.append((model.publish_batch_submit(
+                            topics, compiled_only=True), seqs))
+                    except ColdTables:
+                        # the tables grew: park the lane (C++ drains
+                        # every parked frame, this chunk and the inbox
+                        # included, to Python in order) and reopen after
+                        # the loop top has compiled the new shapes
+                        with self._lane_lock:
+                            self._lane_close()
+                        inbox.clear()
+                        break
                     except Exception:
                         log.exception("lane submit failed; punting")
                         self._lane_respond_punt(seqs)
@@ -1395,9 +1446,10 @@ class NativeBrokerServer:
                 self._lane_respond_punt(seqs)
             if inbox:
                 self._lane_respond_punt([(h, s) for h, s, _ in inbox])
-            if self._lane_on:
-                self._lane_on = False
-                self.host.set_lane(False)
+            with self._lane_lock:
+                if self._lane_on:
+                    self._lane_on = False
+                    self._lane_close()
 
     def _lane_respond(self, seqs, matched, fallback) -> None:
         """``seqs`` are (shard host, seq) pairs: lane sequence numbers
@@ -2789,7 +2841,7 @@ class NativeBrokerServer:
         uses). The entries arrive pre-parsed from C++, so no MQTT
         re-parse runs here — with full-frame copies + parse_one this
         worker's GIL hold was a chunk of the rule-tap tax on the data
-        plane (BENCH_r05 rule_tap_vs_free=0.59). The rest is GIL
+        plane (round-5 CPU bench: rule_tap_vs_free=0.59). The rest is GIL
         latency: rule evaluation is ~20µs/message of pure Python, so
         without explicit releases the poll thread waits up to the 5 ms
         switch interval per GIL acquisition. Discipline: sleep(0)
@@ -3354,6 +3406,10 @@ class NativeBrokerServer:
         """Run the poll loop on a background thread."""
         if self.device_lane == "on":
             self._set_lane(True)
+            # the caller asked for the lane: return once it serves
+            while (self._lane_on and self._lane_thread.is_alive()
+                   and not self.lane_open.wait(0.05)):
+                pass
         if self.fast_path and self.app is not None:
             self._tap_thread = threading.Thread(
                 target=self._tap_worker, name="emqx-rule-tap",

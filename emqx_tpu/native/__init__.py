@@ -16,7 +16,9 @@ Components (see ``src/``):
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import re
 import subprocess
 import threading
 from typing import Iterator, Optional
@@ -31,41 +33,63 @@ _SANITIZE = os.environ.get("EMQX_NATIVE_SANITIZE", "")
 # (-DEMQX_NO_FAULTLINE): bench.py's fault_overhead section compares it
 # against the normal binary to prove disarmed fault sites are free
 _NOFAULT = os.environ.get("EMQX_NATIVE_NOFAULT", "") == "1"
-_LIB_NAME = (f"libemqx_native.{_SANITIZE}.so" if _SANITIZE
-             else "libemqx_native.nofault.so" if _NOFAULT
-             else "libemqx_native.so")
-_LIB_PATH = os.path.join(os.path.dirname(__file__), _LIB_NAME)
+_LIB_STEM = ("libemqx_native" + (f".{_SANITIZE}" if _SANITIZE
+                                  else ".nofault" if _NOFAULT else ""))
+
+
+def _src_digest() -> str:
+    """Digest of every file under src/: the library is keyed by WHAT it
+    was built from, so a stale copy (a tree copied with its build
+    outputs, an mtime reset) is never loaded for other sources."""
+    h = hashlib.sha256()
+    for name in sorted(os.listdir(_SRC_DIR)):
+        h.update(name.encode() + b"\0")
+        with open(os.path.join(_SRC_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+_LIB_PATH = os.path.join(os.path.dirname(__file__),
+                         f"{_LIB_STEM}.{_src_digest()}.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_SRC_DIR, f)) > lib_mtime
-        for f in os.listdir(_SRC_DIR)
-    )
-
-
 def _build() -> None:
+    out = f"{_LIB_PATH}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
         os.path.join(_SRC_DIR, "host.cc"),
         os.path.join(_SRC_DIR, "snappy.cc"),
         os.path.join(_SRC_DIR, "loadgen.cc"),
         os.path.join(_SRC_DIR, "bcrypt.cc"),
-        "-o", _LIB_PATH,
+        "-o", out,
     ]
     if _SANITIZE:
         cmd[1:1] = [f"-fsanitize={_SANITIZE}", "-g",
                     "-fno-omit-frame-pointer"]
     elif _NOFAULT:
         cmd[1:1] = ["-DEMQX_NO_FAULTLINE"]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        # atomic publish: concurrent builders (test workers) never load
+        # a half-written library
+        os.replace(out, _LIB_PATH)
+    finally:
+        if os.path.exists(out):
+            os.remove(out)
+    # drop this variant's libraries built from other sources
+    own = re.compile(re.escape(_LIB_STEM) + r"\.[0-9a-f]{16}\.so")
+    lib_dir = os.path.dirname(_LIB_PATH)
+    for name in os.listdir(lib_dir):
+        path = os.path.join(lib_dir, name)
+        if own.fullmatch(name) and path != _LIB_PATH:
+            try:
+                os.remove(path)
+            except OSError:
+                pass
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -423,7 +447,7 @@ def load() -> Optional[ctypes.CDLL]:
         if _build_error is not None:
             return None
         try:
-            if _needs_build():
+            if not os.path.exists(_LIB_PATH):
                 _build()
             _lib = _bind(ctypes.CDLL(_LIB_PATH))
         except (OSError, subprocess.CalledProcessError) as e:

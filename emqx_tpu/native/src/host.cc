@@ -66,7 +66,7 @@
 //                   DUP. Pre-parsed and
 //                   payload-deduped so the Python rule worker never
 //                   re-parses MQTT (the old full-frame copies were the
-//                   rule-tap tax: BENCH_r05 rule_tap_vs_free=0.59)
+//                   rule-tap tax: round-5 CPU bench, rule_tap_vs_free=0.59)
 //   kind 7 = ACKS   payload = one batched ack/window record per poll
 //                   cycle: [u32 n] + n x ([u64 conn][u32 acked]
 //                   [u32 rel][u32 inflight_now][u32 pending_now])
@@ -431,7 +431,7 @@ inline void BitClr(uint64_t* b, uint32_t i) {
 // first QoS1/2 interaction so a million idle / qos0-only connections
 // pay nothing. Bitmaps replace the round-4 unordered_set bookkeeping:
 // pid allocation and ack-erase are test-and-set bit ops, the profiled
-// hash/alloc churn on the windowed QoS1 path (BENCH_r05's 641k cap).
+// hash/alloc churn on the windowed QoS1 path (the round-5 CPU bench's 641k cap).
 struct AckState {
   // broker-allocated delivery pids, bit i = pid kNativePidBase + i;
   // a qos2 delivery holds its bit across the whole
@@ -3287,7 +3287,7 @@ class Host {
   // record per poll cycle — a per-message record made Python's event
   // decode the data-plane bottleneck (measured: 1.7M -> 0.3M msg/s
   // under a FROM '#' rule). Round 7 copy elision (the remaining
-  // rule-tap tax, BENCH_r05 rule_tap_vs_free=0.59): entries carry the
+  // rule-tap tax, round-5 CPU bench rule_tap_vs_free=0.59): entries carry the
   // PRE-PARSED fields ([u64 publisher][u8 flags][u16 tlen][topic]
   // [u32 plen][payload]) instead of whole-frame copies, so the Python
   // worker never re-parses MQTT while the blast is live, and a payload

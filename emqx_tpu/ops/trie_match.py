@@ -121,25 +121,6 @@ def _g(x: jax.Array) -> jax.Array:
     return jax.lax.optimization_barrier(x)
 
 
-def _register_barrier_batching() -> None:
-    """optimization_barrier has no vmap batching rule in jax<=0.4.x, but
-    it is the identity — batch dims pass straight through.  The sharded
-    match vmaps the kernel over the trie's shard axis, so register the
-    trivial rule (what newer jax ships upstream) when it's missing."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:      # layout moved: newer jax has the rule anyway
-        return
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _rule(args, dims):
-            return optimization_barrier_p.bind(*args), list(dims)
-        batching.primitive_batchers[optimization_barrier_p] = _rule
-
-
-_register_barrier_batching()
-
-
 def _edge_hash(parent: jax.Array, word: jax.Array, mask: int) -> jax.Array:
     """Must stay bit-identical to index.edge_hash (host builder)."""
     h = (

@@ -23,7 +23,7 @@ measured 2.9k lookups/sec at 100K retained):
   round-6 design rebuilt a per-bucket cache on the first lookup after
   any churn, which made exactly the lookup the reference's
   word-position index serves fast (first wildcard match after a churn
-  burst) pay a ~10x rebuild cliff (BENCH_r05
+  burst) pay a ~10x rebuild cliff (round-5 CPU bench:
   retained_lookups_per_sec_cold=11.7k vs 108k warm);
 - topics deeper than ``MAX_LEVELS`` go to a tiny fallback dict walked
   with ``T.match`` (they are rare; correctness is preserved).

@@ -1,15 +1,18 @@
 """Bench-artifact schema lint (ISSUE 17 satellite): every committed
 BENCH_r*.json must carry the fields the bench exists to capture, so a
-future run can't silently drop them the way r05 dropped
+future run can't silently drop them the way the round-5 run dropped
 ``kernel_platform`` (renamed to ``platform`` by _compose and discarded).
 
 The artifact wrapper is driver-written: ``{"n", "cmd", "rc", "tail",
 "parsed"}`` with the bench's own cumulative JSON line under ``parsed``.
 
 Grandfathering is explicit and frozen: rounds that PREDATE a field are
-exempt from it (r01–r04 predate the probe capture, r05 predates
-kernel_platform retention and the tenm/sharded arms); everything from
-r06 on must carry the full set.
+exempt from it (r02 and r04 predate the probe capture); everything from
+r06 on must carry the full set. The r01, r03 and r05 records were
+deleted in PR 21: r01 was taken through an access layer and with a
+kernel that no longer exist, r03 and r05 are CPU runs of device
+sections. Device numbers are not measured until the ledger-grade
+benchmark lands.
 """
 
 import json

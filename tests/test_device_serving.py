@@ -251,7 +251,7 @@ def test_adaptive_knee_tracks_measured_costs():
     class FakeBroker:
         model = object()
     p = PublishPipeline(FakeBroker(), cm=None)
-    p._rtt_ema = 0.070          # tunneled chip
+    p._rtt_ema = 0.070          # a 70 ms device round trip
     p._host_cost_ema = 5e-6     # measured oracle walk
     assert p.device_knee() == p.max_batch      # saturates at max_batch
     p._rtt_ema = 0.001          # local chip

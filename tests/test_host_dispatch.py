@@ -1,8 +1,8 @@
 """Platform-aware kernel dispatch (ISSUE 17 satellite): on a cpu
 backend RouterModel.publish_batch serves from the host matcher (the C++
 SubTable, or the oracle Trie when the native plane didn't build)
-instead of the XLA program — BENCH_r05 measured the XLA kernel at 0.1x
-the host matcher on CPU, a regression we used to serve.
+instead of the XLA program — the round-5 CPU bench measured the XLA
+kernel at 0.1x the host matcher on CPU, a regression we used to serve.
 
 ``EMQX_TPU_CPU_KERNEL`` is the escape hatch: ``xla`` (what conftest
 pins for the rest of the suite) forces the device kernel so CPU CI

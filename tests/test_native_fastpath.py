@@ -1139,6 +1139,58 @@ def test_device_lane_disable_drains_to_python():
     server.stop()
 
 
+def test_device_lane_recompiles_parked_when_tables_grow():
+    """The lane opens only after every program it can launch is
+    compiled, and tables that outgrow those shapes park it (parked
+    frames drain to Python, in order) until the new shapes compile —
+    no frame ever waits on a compile, so the stale deadline never
+    trips, and delivery stays complete and ordered throughout."""
+    app = _lane_app()
+    model = app.broker.model
+    server = NativeBrokerServer(port=0, app=app, device_lane="on")
+    server.start()
+    assert server.lane_open.is_set()
+    assert "step/16384" in server.lane_compile_s
+
+    async def main():
+        sub = MqttClient(port=server.port, clientid="dgs")
+        await sub.connect()
+        await sub.subscribe("dg/+", qos=0)
+        pub = MqttClient(port=server.port, clientid="dgp")
+        await pub.connect()
+        await pub.publish("dg/t", b"w", qos=0)   # slow path, earns permit
+        await sub.recv(timeout=20)
+        await _settle(0.5)
+        await pub.publish("dg/t", b"laned", qos=0)
+        assert (await sub.recv(timeout=20)).payload == b"laned"
+        assert await _wait_fast(server, "lane_out", 1)
+        shapes = model._table_shapes()
+        for i in range(3000):                     # outgrow the tables
+            model.subscribe(f"grow/{i}/leaf", i % 64)
+        for i in range(40):
+            await pub.publish("dg/t", str(i).encode(), qos=0)
+        got = [int((await sub.recv(timeout=30)).payload) for _ in range(40)]
+        assert got == list(range(40)), got[:10]
+        deadline = time.monotonic() + 60
+        while not server.lane_open.is_set():
+            assert time.monotonic() < deadline, "lane never reopened"
+            await asyncio.sleep(0.05)
+        assert model._table_shapes() != shapes
+        # the drain revoked the topic's permit: re-earn it, then lane
+        await pub.publish("dg/t", b"re-earn", qos=0)
+        assert (await sub.recv(timeout=20)).payload == b"re-earn"
+        await _settle(0.5)
+        out0 = server.fast_stats()["lane_out"]
+        await pub.publish("dg/t", b"after", qos=0)
+        assert (await sub.recv(timeout=20)).payload == b"after"
+        assert await _wait_fast(server, "lane_out", out0 + 1)
+        assert server.fast_stats()["lane_stale"] == 0
+        await sub.close(); await pub.close()
+
+    run(main())
+    server.stop()
+
+
 def test_match_filter_union_equals_walk():
     """Differential: for random topics, the union of MatchFilter over
     the oracle's matched filters must equal the walk's match set — the

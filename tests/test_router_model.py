@@ -309,3 +309,148 @@ def test_inflight_fid_quarantine_prevents_wrong_delivery():
     assert model.index._inflight == 0
     f2 = model.publish_batch(["new/topic"])
     assert f2[0][0] == ["new/topic"] and f2[2][0] == [5]
+
+
+class _CompileCounter:
+    """Counts XLA backend compiles while active."""
+
+    def __enter__(self):
+        import jax
+
+        self.n = 0
+
+        def listener(name, _secs, **_kw):
+            if name.endswith("backend_compile_duration"):
+                self.n += 1
+        self._listener = listener
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+
+        jax.monitoring.unregister_event_duration_listener(self._listener)
+
+
+def test_warm_compiles_every_serving_program():
+    """After warm(max_batch) no batch bucket up to max_batch and no
+    patch bucket compiles again — the lane's frames never wait on XLA."""
+    from emqx_tpu.models import router_model as rm
+
+    m = make_model()
+    seconds = m.warm(1024)
+    assert set(seconds) == (
+        {f"step/{b}" for b in (64, 128, 256, 512, 1024)}
+        | {f"patch/{c}" for c in rm.PATCH_BUCKETS})
+    assert m.warm(1024) == {}          # already warm: compiles nothing
+    with _CompileCounter() as cc:
+        for n in (1, 64, 65, 300, 1000):
+            m.publish_batch_submit(["a/b/c"] * n, compiled_only=True)
+        m.subscribe("a/new/leaf", 9)   # one incremental patch
+        matched, _, _, _ = m.publish_batch(["a/new/leaf"])
+    assert "a/new/leaf" in matched[0]
+    assert m.patch_count >= 1
+    assert cc.n == 0
+
+
+def test_compiled_only_submit_refuses_cold_programs():
+    """A batch past the warmed buckets, or tables that grew since the
+    warm, raise ColdTables before any launch; warm() clears it."""
+    from emqx_tpu.models.router_model import ColdTables
+
+    m = make_model()
+    m.warm(128)
+    launches = m.launch_count
+    with pytest.raises(ColdTables):
+        m.publish_batch_submit(["x/y"] * 129, compiled_only=True)
+    for i in range(3000):                # outgrow the node headroom
+        m.subscribe(f"grow/{i}/leaf", i % 256)
+    with pytest.raises(ColdTables):
+        m.publish_batch_submit(["grow/7/leaf"], compiled_only=True)
+    assert m.launch_count == launches
+    assert m.warm(128)                   # new shapes compile
+    matched, _, _, _ = m.publish_batch_collect(
+        m.publish_batch_submit(["grow/7/leaf"], compiled_only=True))
+    assert sorted(matched[0]) == ["#", "grow/7/leaf"]
+
+
+def test_pool_growth_with_pending_trie_updates_compiles_nothing():
+    """The rowmap outgrows its capacity while the trie keeps its shapes
+    and holds pending updates: a compiled_only submit re-uploads instead
+    of scattering at the new shapes, and refuses before any compile."""
+    from emqx_tpu.models.router_model import ColdTables
+
+    m = make_model()
+    for i in range(40):
+        m.subscribe(f"d/{i}/x/y/z/w", i)
+    m.warm(128)
+    trie_shapes = m._table_shapes()[:-2]
+    rowmap_cap = m._rowmap_host.shape[0]
+    # filters on nodes that already exist: no trie growth, only pending
+    # node updates, and more fids than the rowmap holds
+    for i in range(40):
+        for f in ("d/{}", "d/{}/x", "d/{}/x/y", "d/{}/x/y/z"):
+            m.subscribe(f.format(i), i)
+    assert len(m.index.filters) > rowmap_cap
+    assert not m.index.needs_rebuild and m.index.pending
+    with _CompileCounter() as cc:
+        with pytest.raises(ColdTables):
+            m.publish_batch_submit(["d/3/x"], compiled_only=True)
+    assert cc.n == 0
+    assert m._table_shapes()[:-2] == trie_shapes
+    assert m._rowmap_host.shape[0] > rowmap_cap
+    assert m.warm(128)
+    matched, _, _, _ = m.publish_batch_collect(
+        m.publish_batch_submit(["d/3/x"], compiled_only=True))
+    assert sorted(matched[0]) == ["#", "d/3/x"]
+
+
+def test_warm_leaves_the_model_lock_free_while_compiling():
+    """warm() compiles outside the model lock: a subscribe from another
+    thread lands while the compiles run."""
+    import threading
+
+    m = make_model()
+    m.publish_batch(["a/b/c"])
+    done = threading.Event()
+    compiling = threading.Event()
+    real_lower = m._step.lower
+
+    class _Step:
+        def lower(self, *args):
+            compiling.set()
+            assert done.wait(30)   # the subscribe below got the lock
+            return real_lower(*args)
+
+    step, m._step = m._step, _Step()
+    t = threading.Thread(target=m.warm, args=(64,))
+    t.start()
+    assert compiling.wait(30)
+    m.subscribe("late/x", 1)
+    done.set()
+    t.join(60)
+    m._step = step
+    assert m._warm_batch == 64
+    assert m.publish_batch(["late/x"])[0][0] == ["#", "late/x"]
+
+
+def test_patch_past_top_bucket_reuploads_same_shapes():
+    """A drain larger than the top patch rung re-uploads the tables at
+    their current shapes instead of compiling a new scatter."""
+    from emqx_tpu.models import router_model as rm
+
+    top = rm.PATCH_BUCKETS[-1]
+    m = RouterModel(TrieIndex(max_levels=8), n_sub_slots=64, K=16, M=32)
+    for i in range(top + 100):
+        m.subscribe(f"seed/{i}/x", i % 64)
+    m.publish_batch(["seed/1/x"])
+    shapes, uploads = m._table_shapes(), m.upload_count
+    for i in range(top + 50):          # one node_fid write per delete
+        m.unsubscribe(f"seed/{i}/x", i % 64)
+    assert max(map(len, m.index.pending.values())) > top
+    assert not m.index.needs_rebuild
+    live = f"seed/{top + 60}/x"
+    matched, _, _, _ = m.publish_batch([live, "seed/0/x"])
+    assert matched == [[live], []]
+    assert m.upload_count == uploads + 1
+    assert m._table_shapes() == shapes

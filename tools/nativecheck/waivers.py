@@ -74,4 +74,10 @@ LOCK_ORDER = [
     {"order": "_tele_lock < _closed_lock",
      "why": "_on_spans holds _tele_lock across _conninfo_for's "
             "_closed_conns read"},
+    # a lane drain revokes the drained topics' permits, so closing the
+    # lane forgets the Python grant record under _permit_lock
+    {"order": "_lane_lock < _permit_lock",
+     "why": "_lane_drained (called under _lane_lock) clears _granted "
+            "under _permit_lock; never take _lane_lock while holding "
+            "_permit_lock"},
 ]

@@ -122,16 +122,9 @@ class BrokerApp:
         if (self.broker.model is not None
                 and getattr(self.broker.model, "kernel_telemetry", False)):
             from emqx_tpu.observe.device_metrics import DeviceMetricsFold
-            from emqx_tpu.observe.trace import SpanCollector
             self.device_metrics = DeviceMetricsFold(
-                self.metrics, ledger=self.ledger, spans=SpanCollector(),
-                model=self.broker.model, node=node)
+                self.metrics, ledger=self.ledger, model=self.broker.model)
             self.broker.model.telemetry = self.device_metrics
-            # the kernel fold's sampled traces serve the tracing-spans
-            # mgmt surface when no native server attaches (a booted
-            # native server overrides this with its own richer ring)
-            if self.native_spans_fn is None:
-                self.native_spans_fn = self.device_metrics.spans_recent
         self.sys = SysHeartbeat(
             node=node, publish_fn=self._publish_dispatch,
             metrics=self.metrics, stats=self.stats, ledger=self.ledger,

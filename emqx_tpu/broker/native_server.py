@@ -61,6 +61,7 @@ always correct):
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import queue
@@ -72,6 +73,8 @@ import zlib
 from collections import OrderedDict, deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
+
+import numpy as np
 
 from emqx_tpu import native
 from emqx_tpu.broker.broker import Broker
@@ -96,6 +99,14 @@ LANE_AUTO_ON_FILTERS = 50_000
 LANE_AUTO_OFF_FILTERS = 25_000
 LANE_MAX_BATCH = 16_384
 LANE_PIPE_DEPTH = 2          # submitted-but-uncollected device batches
+# the lane segments the pump times, each a latency.lane.<name> histogram
+# (every lane message, CLOCK_MONOTONIC; the host times read -> park and
+# hand-off -> flush, native.HIST_STAGES lane_park/lane_deliver):
+#   wait   = park -> the pump starts submitting the message's batch
+#   submit = publish_batch_submit (each message takes its batch's time)
+#   device = submit returned -> results on the host (the model's stamp)
+#   decode = results on the host -> response handed to the host
+LANE_SEGMENTS = ("wait", "submit", "device", "decode")
 LANE_STALE_BACKOFF_S = 30.0  # sit-out after a C++ stale trip
 TRUNK_RETRY_S = 1.0          # redial cadence for a down trunk peer
 TRUNK_RETRY_CAP_S = 30.0     # exponential-backoff ceiling
@@ -691,6 +702,10 @@ class NativeBrokerServer:
         for stage in native.HIST_STAGES:
             self._hists[stage] = self.broker.metrics.register_hist(
                 f"latency.native.{stage}")
+        # written by the lane pump thread alone
+        self._lane_hists = {
+            seg: self.broker.metrics.register_hist(f"latency.lane.{seg}")
+            for seg in LANE_SEGMENTS}
         # per-shard stage breakdown (the bench's shards section reads
         # it via shard_latency_summary): registered only when sharded,
         # so the unsharded metric surface is byte-identical to round 11
@@ -1362,19 +1377,37 @@ class NativeBrokerServer:
         matched filter strings. Every failure answers 'punt' so the
         frames take the always-correct Python path. Opening (and
         reopening after the tables grow) compiles first: see
-        ``_lane_warm_open``."""
+        ``_lane_warm_open``.
+
+        With telemetry on, every answered batch feeds the
+        ``latency.lane.*`` segments (``_lane_time``) and the pump's
+        phases show on a profiler's timeline as ``lane.idle`` (no
+        message to submit, blocked on the queue), ``lane.gate`` (the
+        pipeline full at LANE_PIPE_DEPTH: new messages wait until the
+        oldest launch is collected) and ``lane.respond`` (the answer
+        packed and handed to the host)."""
         from emqx_tpu.models.router_model import ColdTables
 
         model = self._lane_model()
-        pending: deque = deque()   # submitted, uncollected device batches
-        inbox: deque = deque()     # (seq, topic) awaiting submission
+        timed = self.telemetry
+        if timed:
+            from jax.profiler import TraceAnnotation as span
+        else:
+            span = contextlib.nullcontext
+        # submitted, uncollected device batches:
+        # (handle, lane items, submit start ns, submit end ns)
+        pending: deque = deque()
+        # lane items (shard host, seq, topic, park ns, trace id)
+        # awaiting submission
+        inbox: deque = deque()
         try:
             while not self._lane_stop.is_set():
                 if self._lane_on and not self.lane_open.is_set():
                     self._lane_warm_open(model)
                 try:
-                    items = self._lane_q.get(
-                        timeout=0.0005 if (pending or inbox) else 0.05)
+                    with span("lane.gate" if inbox else "lane.idle"):
+                        items = self._lane_q.get(
+                            timeout=0.0005 if (pending or inbox) else 0.05)
                 except queue.Empty:
                     items = None
                 if items:
@@ -1391,13 +1424,15 @@ class NativeBrokerServer:
                 while inbox and len(pending) < LANE_PIPE_DEPTH:
                     n = min(len(inbox), LANE_MAX_BATCH)
                     chunk = [inbox.popleft() for _ in range(n)]
-                    # items are (shard host, seq, topic): one device
-                    # batch may mix shards, the response splits per host
-                    seqs = [(h, s) for h, s, _ in chunk]
-                    topics = [t for _, _, t in chunk]
+                    # one device batch may mix shards, the response
+                    # splits per host
+                    topics = [it[2] for it in chunk]
                     try:
-                        pending.append((model.publish_batch_submit(
-                            topics, compiled_only=True), seqs))
+                        t_sub = time.monotonic_ns()
+                        handle = model.publish_batch_submit(
+                            topics, compiled_only=True)
+                        pending.append(
+                            (handle, chunk, t_sub, time.monotonic_ns()))
                     except ColdTables:
                         # the tables grew: park the lane (C++ drains
                         # every parked frame, this chunk and the inbox
@@ -1409,17 +1444,24 @@ class NativeBrokerServer:
                         break
                     except Exception:
                         log.exception("lane submit failed; punting")
-                        self._lane_respond_punt(seqs)
+                        self._lane_respond_punt(chunk)
                 if pending and (len(pending) >= LANE_PIPE_DEPTH
                                 or (items is None and not inbox)):
-                    handle, seqs = pending.popleft()
+                    # a full pipeline forces this collect: whatever
+                    # arrived meanwhile waits in the queue or the inbox
+                    gate = (span("lane.gate")
+                            if len(pending) >= LANE_PIPE_DEPTH
+                            else contextlib.nullcontext())
+                    handle, chunk, t_sub, t_subd = pending.popleft()
                     try:
-                        matched, aux, _slots, fallback = \
-                            model.publish_batch_collect(handle)
+                        with gate:
+                            matched, aux, _slots, fallback = \
+                                model.publish_batch_collect(handle)
                     except Exception:
                         log.exception("lane collect failed; punting")
-                        self._lane_respond_punt(seqs)
+                        self._lane_respond_punt(chunk)
                         continue
+                    t_ready = model.results_ready_ns()
                     if aux and any(aux):
                         # aux = co-batched rule FROM filters: they map
                         # to the C++ RULE-TAP entries, so the response
@@ -1431,11 +1473,16 @@ class NativeBrokerServer:
                         matched = [
                             m + [x for x in a if x not in m] if a else m
                             for m, a in zip(matched, aux)]
-                    self._lane_respond(seqs, matched, fallback)
+                    with span("lane.respond"):
+                        handed = self._lane_respond(chunk, matched,
+                                                    fallback)
+                    if timed:
+                        self._lane_time(chunk, t_sub, t_subd, t_ready,
+                                        handed)
         except Exception:
             log.exception("lane pump died; lane off")
         finally:
-            for handle, seqs in pending:
+            for handle, chunk, _t, _u in pending:
                 # collect (not just punt): publish_batch_submit opened
                 # an inflight window on the index — skipping the
                 # collect would quarantine freed filter ids forever
@@ -1443,23 +1490,59 @@ class NativeBrokerServer:
                     model.publish_batch_collect(handle)
                 except Exception:
                     pass
-                self._lane_respond_punt(seqs)
+                self._lane_respond_punt(chunk)
             if inbox:
-                self._lane_respond_punt([(h, s) for h, s, _ in inbox])
+                self._lane_respond_punt(inbox)
             with self._lane_lock:
                 if self._lane_on:
                     self._lane_on = False
                     self._lane_close()
 
-    def _lane_respond(self, seqs, matched, fallback) -> None:
-        """``seqs`` are (shard host, seq) pairs: lane sequence numbers
+    def _lane_time(self, chunk, t_sub: int, t_subd: int, t_ready: int,
+                   handed: dict) -> None:
+        """Fold one answered batch into the pump's segment histograms
+        (each message weighs one, in a few vectorized observations) and
+        put its sampled messages' ``kernel_submit``/``kernel_collect``
+        points on their timelines. ``handed`` maps each host to the
+        stamp its response was handed over at."""
+        park = np.fromiter((it[3] for it in chunk), np.int64, len(chunk))
+        timed = park > 0      # parked with the host's telemetry on
+        n = int(timed.sum())
+        t_ready = max(t_ready, t_subd)
+        if n:
+            hists = self._lane_hists
+            hists["wait"].observe_many(t_sub - park[timed])
+            hists["submit"].observe_n(t_subd - t_sub, n)
+            hists["device"].observe_n(t_ready - t_subd, n)
+            if len(handed) == 1:
+                (t_hand,) = handed.values()
+                hists["decode"].observe_n(t_hand - t_ready, n)
+            else:
+                per: dict = {}
+                for it, ok in zip(chunk, timed.tolist()):
+                    if ok:
+                        per[it[0]] = per.get(it[0], 0) + 1
+                for h, k in per.items():
+                    hists["decode"].observe_n(handed[h] - t_ready, k)
+        node = self.broker.node
+        for it in chunk:
+            if it[4]:
+                self.spans.record(it[4], "kernel_submit", t_sub,
+                                  aux=len(chunk), node=node)
+                self.spans.record(it[4], "kernel_collect", t_ready,
+                                  aux=len(chunk), node=node)
+
+    def _lane_respond(self, chunk, matched, fallback) -> dict:
+        """``chunk`` holds the batch's lane items: lane sequence numbers
         are per-host counters, so each response blob goes back to the
-        host whose poll loop parked the frame."""
+        host whose poll loop parked the frame. Returns each host's
+        hand-off stamp (the start of its lane_deliver stage)."""
         fb = set(fallback or ())
         pack = struct.pack
         per: dict = {}
-        for i, (h, seq) in enumerate(seqs):
-            per.setdefault(h, []).append((i, seq))
+        for i, it in enumerate(chunk):
+            per.setdefault(it[0], []).append((i, it[1]))
+        handed = {}
         for h, items in per.items():
             parts = [pack("<I", len(items))]
             for i, seq in items:
@@ -1474,12 +1557,15 @@ class NativeBrokerServer:
                     b = f.encode()
                     parts.append(pack("<H", len(b)))
                     parts.append(b)
-            h.lane_deliver(b"".join(parts))
+            blob = b"".join(parts)
+            handed[h] = time.monotonic_ns()
+            h.lane_deliver(blob, handed[h])
+        return handed
 
-    def _lane_respond_punt(self, seqs) -> None:
+    def _lane_respond_punt(self, chunk) -> None:
         per: dict = {}
-        for h, seq in seqs:
-            per.setdefault(h, []).append(seq)
+        for it in chunk:
+            per.setdefault(it[0], []).append(it[1])
         for h, ss in per.items():
             parts = [struct.pack("<I", len(ss))]
             for seq in ss:
@@ -2651,8 +2737,8 @@ class NativeBrokerServer:
                 # (lane seqs are per-host counters)
                 if lane_buf is None:
                     lane_buf = []
-                lane_buf.append(
-                    (host, conn_id, payload.decode("utf-8", "replace")))
+                park_ns, tid, topic = native.parse_lane(payload)
+                lane_buf.append((host, conn_id, topic, park_ns, tid))
             elif kind == native.EV_TAP:
                 self._on_tap(conn_id, payload)
             elif kind == native.EV_ACKS:

@@ -420,6 +420,9 @@ class RouterModel:
         # DeviceMetricsFold attach point (observe/device_metrics.py);
         # the model never imports the observe plane — the app wires it
         self.telemetry = None
+        # per collecting thread: when its last collected batch's results
+        # reached the host (results_ready_ns)
+        self._ready = threading.local()
         self.patch_upload_bytes = 0   # unpadded dirty bytes scattered
         # what warm() compiled for: table shapes and the top batch bucket
         self._warm_shapes: Optional[tuple] = None
@@ -912,6 +915,7 @@ class RouterModel:
         """Stage 2: fetch + decode a submitted batch's results."""
         if isinstance(pending, tuple) and len(pending) == 2 \
                 and pending[0] == "host":
+            self._ready.ns = time.monotonic_ns()
             return pending[1]
         (topics, too_long, fids, fanout, overflow, fan_any, counters,
          (t0, t1)) = pending
@@ -938,6 +942,7 @@ class RouterModel:
                 fids, overflow, fan_any = jax.device_get(
                     (fids, overflow, fan_any))
             t3 = time.monotonic_ns()
+            self._ready.ns = t3
             if fan_any:
                 fan = np.asarray(fanout)
             else:
@@ -949,16 +954,22 @@ class RouterModel:
             if tel is not None:
                 try:   # telemetry must never break the serving path
                     tel.on_batch(
-                        counters, n_topics=len(topics),
+                        counters,
                         submit_ns=t1 - t0, step_ns=t3 - t2,
-                        decode_ns=time.monotonic_ns() - t3,
-                        t_submit_ns=t0, t_collect_ns=t3)
+                        decode_ns=time.monotonic_ns() - t3)
                 except Exception:  # noqa: BLE001 — observe-plane bug
                     pass
             return res
         finally:
             with self._mlock:
                 self.index.end_inflight()
+
+    def results_ready_ns(self) -> int:
+        """When the results of the batch this thread collected last were
+        on the host (CLOCK_MONOTONIC ns): the stamp after the collect's
+        one ``device_get``, or the collect call itself on the host
+        matcher. 0 before this thread's first collect."""
+        return getattr(self._ready, "ns", 0)
 
     def _publish_batch_host(self, topics):
         """Serve one batch from the host matcher (cpu-platform path).

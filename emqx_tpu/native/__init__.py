@@ -173,7 +173,7 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.emqx_host_set_lane.argtypes = [ctypes.c_void_p, ctypes.c_int]
     lib.emqx_host_lane_deliver.restype = ctypes.c_int
     lib.emqx_host_lane_deliver.argtypes = [
-        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t]
+        ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint64]
     lib.emqx_host_lane_backlog.restype = ctypes.c_long
     lib.emqx_host_lane_backlog.argtypes = [ctypes.c_void_p]
     lib.emqx_host_set_max_qos.restype = ctypes.c_int
@@ -519,6 +519,15 @@ EV_COAP = 13        # CoAP exchange degraded whole to the Python oracle
                     # (round 19): payload = the raw datagram verbatim
 
 
+def parse_lane(payload: bytes) -> tuple[int, int, str]:
+    """Decode one kind-4 lane record's payload into ``(park_ns,
+    trace_id, topic)``: park_ns is the CLOCK_MONOTONIC park stamp (0
+    with telemetry off), trace_id the sampler's id (0 = unsampled)."""
+    return (int.from_bytes(payload[0:8], "little"),
+            int.from_bytes(payload[8:16], "little"),
+            payload[16:].decode("utf-8", "replace"))
+
+
 def parse_durable(payload: bytes) -> tuple[int, int, list[tuple]]:
     """Decode one kind-10 durable record into ``(base_guid, ts_ms,
     [(origin_conn, flags, [tokens], topic, payload, trace_id, cid),
@@ -667,7 +676,13 @@ HIST_STAGES = ("ingress_route", "route_flush", "qos1_rtt", "qos2_rtt",
                # coap gateway plane (round 19): coap_ingest = sampled
                # CoAP datagram decode+dispatch; observe_notify = one
                # observe notification resolve+encode+write
-               "coap_ingest", "observe_notify")
+               "coap_ingest", "observe_notify",
+               # device lane (every lane message, CLOCK_MONOTONIC):
+               # lane_park = socket read -> park; lane_deliver = pump
+               # hand-off -> flush done; lane_total = read -> flush
+               # done. The pump records the segments in between
+               # (native_server.LANE_SEGMENTS)
+               "lane_park", "lane_deliver", "lane_total")
 
 # flight-recorder event codes (host.cc FrEvent)
 FR_EVENT_NAMES = {1: "open", 2: "frame", 3: "punt", 4: "fast_pub",
@@ -1704,9 +1719,12 @@ class NativeHost:
         parked frame to the Python slow path in arrival order."""
         self._lib.emqx_host_set_lane(self._h, 1 if enabled else 0)
 
-    def lane_deliver(self, blob: bytes) -> None:
-        """Apply one pump response blob (see host.cc LaneDeliver)."""
-        self._lib.emqx_host_lane_deliver(self._h, blob, len(blob))
+    def lane_deliver(self, blob: bytes, handed_ns: int = 0) -> None:
+        """Apply one pump response blob (see host.cc LaneDeliver).
+        ``handed_ns`` is the hand-off stamp (``time.monotonic_ns()``)
+        the lane_deliver stage starts from; 0 stamps it here."""
+        self._lib.emqx_host_lane_deliver(self._h, blob, len(blob),
+                                         handed_ns)
 
     def lane_backlog(self) -> int:
         return int(self._lib.emqx_host_lane_backlog(self._h))

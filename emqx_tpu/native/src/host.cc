@@ -57,7 +57,10 @@
 //                   connections accepted on the WebSocket listener)
 //   kind 2 = FRAME  payload = one complete MQTT frame (verbatim bytes)
 //   kind 3 = CLOSED payload = reason string
-//   kind 4 = LANE   conn_id = lane seq, payload = topic (device match)
+//   kind 4 = LANE   conn_id = lane seq, payload = [u64 park_ns]
+//                   [u64 trace_id][topic] (device match): park_ns is
+//                   the CLOCK_MONOTONIC park stamp (0 with telemetry
+//                   off), trace_id the sampler's id (0 = unsampled)
 //   kind 6 = TAP    payload = batched rule-tap records, one entry per
 //                   tapped publish: [u64 publisher][u8 flags][u16 tlen]
 //                   [topic] + (flags bit0 ? [u32 plen][payload] :
@@ -264,6 +267,11 @@ enum HistStage {
                           // trunk_batch_n convention)
   kHistCoapIngest,        // sampled: CoAP datagram decode+dispatch
   kHistObserveNotify,     // sampled: observe notify resolve+encode+write
+  // device-lane segments, every lane message (one clock, CLOCK_MONOTONIC
+  // ns; the pump records the middle four on the Python side):
+  kHistLanePark,          // socket read -> lane park
+  kHistLaneDeliver,       // verdict handed to the host -> flush done
+  kHistLaneTotal,         // socket read -> flush done (the whole lane)
   kHistCount
 };
 
@@ -1540,7 +1548,7 @@ class Host {
         if (!lane_enabled_) LaneDrainToPython();
         break;
       case Op::kLaneDeliver:
-        LaneDeliver(op.str);
+        LaneDeliver(op.str, op.token);
         break;
       case Op::kSetMaxQos:
         max_qos_allowed_ = op.qos;
@@ -1783,6 +1791,8 @@ class Host {
     uint8_t qos = 0;
     uint16_t pid = 0;
     uint64_t enq_ms = 0;
+    uint64_t rx_ns = 0;   // the socket read that brought it (telemetry)
+    uint64_t trace = 0;   // sampled trace id (0 = unsampled)
     std::string frame;  // original PUBLISH bytes (punts forward these)
     uint32_t topic_off = 0, topic_len = 0, payload_off = 0;
   };
@@ -2014,7 +2024,10 @@ class Host {
   //   [u32 count] then per item
   //   [u64 seq][u8 flags][u16 nf] + nf x ([u16 len][filter bytes])
   // flags bit0 = punt (device overflow / tokenizer reject / pump spill).
-  void LaneDeliver(const std::string& blob) {
+  // ``handed_ns`` is when the pump handed the blob over: every answered
+  // entry records handed -> flush done and read -> flush done.
+  void LaneDeliver(const std::string& blob, uint64_t handed_ns) {
+    lane_rx_scratch_.clear();
     size_t pos = 0;
     auto need = [&](size_t n) { return pos + n <= blob.size(); };
     auto rd_u16 = [&]() {
@@ -2045,6 +2058,7 @@ class Host {
       if (it == lane_pending_.end()) continue;  // drained/stale already
       LaneEntry le = std::move(it->second);
       lane_pending_.erase(it);
+      if (telemetry_ && le.rx_ns) lane_rx_scratch_.push_back(le.rx_ns);
       if (telemetry_) {
         // lane dwell (enqueue -> device verdict applied): ms-scale by
         // nature (a device round trip), so the coarse clock suffices
@@ -2139,9 +2153,10 @@ class Host {
         continue;
       }
       bool ldup = (static_cast<uint8_t>(le.frame[0]) & 0x08) != 0;
-      // lane deliveries are native-consumed publishes too: same
-      // sampling commit point as the walk path (shared ticker)
-      TraceSample(le.publisher);
+      // the sampler ticked at park (its ingress point is the read):
+      // the rest of the timeline continues under the parked id
+      cur_trace_ = le.trace;
+      cur_trace_delivers_ = 0;
       if (tapped) EmitTap(le.publisher, le.qos, ldup, topic, payload);
       stats_[kStLaneOut].fetch_add(1, std::memory_order_relaxed);
       if (le.qos == 1)
@@ -2174,6 +2189,14 @@ class Host {
       }
     }
     FlushDirty();
+    if (!lane_rx_scratch_.empty()) {
+      uint64_t done = NowNs();
+      for (uint64_t rx : lane_rx_scratch_) {
+        RecordHist(kHistLaneDeliver, done > handed_ns ? done - handed_ns : 0);
+        RecordHist(kHistLaneTotal, done > rx ? done - rx : 0);
+      }
+      lane_rx_scratch_.clear();
+    }
   }
 
   void HandleEvent(const epoll_event& ev) {
@@ -2776,6 +2799,7 @@ class Host {
       ssize_t n = FaultRecv(fault::kSiteConnRead, id, c.fd, chunk,
                             sizeof(chunk));
       if (n > 0) {
+        if (telemetry_) rx_ns_ = NowNs();  // lane messages' read stamp
         bool ok;
         if (c.ws) {
           ok = WsIngest(id, c, chunk, static_cast<size_t>(n));
@@ -2795,6 +2819,7 @@ class Host {
         break;
       }
     }
+    rx_ns_ = 0;
     FlushDirty();
   }
 
@@ -3158,10 +3183,24 @@ class Host {
         le.payload_off = static_cast<uint32_t>(pos);
         le.frame = f;
         stats_[kStLaneIn].fetch_add(1, std::memory_order_relaxed);
-        if (telemetry_)  // arg2=1 marks a lane park, not a walk
+        uint64_t park_ns = 0;
+        if (telemetry_) {
+          // arg2=1 marks a lane park, not a walk
           FrNote(c, kFrFastPub, 3, qos, cur_hash_, 1);
-        events_.push_back(
-            EncodeRecord(4, seq, topic.data(), topic.size()));
+          park_ns = NowNs();
+          le.rx_ns = rx_ns_ && rx_ns_ <= park_ns ? rx_ns_ : park_ns;
+          RecordHist(kHistLanePark, park_ns - le.rx_ns);
+          // the sampling commit point of a lane message is its park;
+          // its ingress point is the read that brought it in
+          TraceSample(id, le.rx_ns);
+          le.trace = cur_trace_;
+          cur_trace_ = 0;
+        }
+        std::string rec(16 + topic.size(), '\0');  // kind-4 payload
+        memcpy(&rec[0], &park_ns, 8);
+        memcpy(&rec[8], &le.trace, 8);
+        memcpy(&rec[16], topic.data(), topic.size());
+        events_.push_back(EncodeRecord(4, seq, rec.data(), rec.size()));
         LaneEnqueue(seq, std::move(le));
         return true;
       }
@@ -5024,6 +5063,7 @@ class Host {
         if (errno == EINTR) continue;
         break;  // EAGAIN: drained
       }
+      if (telemetry_) rx_ns_ = NowNs();  // lane messages' read stamp
       for (int i = 0; i < n; i++) {
         if (mm[i].msg_len == 0) continue;
         const uint8_t* d = sn_rx_buf_.data() + i * kSnRecvBuf;
@@ -5037,6 +5077,7 @@ class Host {
       }
       if (n < kSnRecvBatch) break;  // drained
     }
+    rx_ns_ = 0;
     FlushDirty();
   }
 
@@ -5964,6 +6005,7 @@ class Host {
         if (errno == EINTR) continue;
         break;  // EAGAIN: drained
       }
+      if (telemetry_) rx_ns_ = NowNs();  // lane messages' read stamp
       for (int i = 0; i < n; i++) {
         if (mm[i].msg_len == 0) continue;
         const uint8_t* d = coap_rx_buf_.data() + i * kCoapRecvBuf;
@@ -5988,6 +6030,7 @@ class Host {
       }
       if (n < kCoapRecvBatch) break;  // drained
     }
+    rx_ns_ = 0;
     FlushDirty();
   }
 
@@ -7078,7 +7121,7 @@ class Host {
   // (kTraceMaxPerCycle): a blast cycle draining thousands of publishes
   // clips its extra picks instead of flooding the span plane.
   // @admit-gated — the commit point sits AFTER every punt decision
-  void TraceSample(uint64_t publisher) {
+  void TraceSample(uint64_t publisher, uint64_t t_ns = 0) {
     cur_trace_ = 0;
     if (!telemetry_ || !tracing_) return;
     if ((++trace_tick_ & trace_mask_) != 0) return;
@@ -7087,11 +7130,13 @@ class Host {
     cur_trace_ = NextTraceId();
     cur_trace_delivers_ = 0;
     stats_[kStTracedPubs].fetch_add(1, std::memory_order_relaxed);
-    SpanNote(kSpanIngress, publisher);
+    SpanNote(kSpanIngress, publisher, 0, t_ns);
   }
 
-  // Emit one span point for the active (or explicitly named) trace.
-  void SpanNote(uint8_t stage, uint64_t aux, uint64_t trace = 0) {
+  // Emit one span point for the active (or explicitly named) trace, at
+  // ``t_ns`` when given, else now.
+  void SpanNote(uint8_t stage, uint64_t aux, uint64_t trace = 0,
+                uint64_t t_ns = 0) {
     if (!telemetry_) return;
     uint64_t tid = trace ? trace : cur_trace_;
     if (!tid) return;
@@ -7099,7 +7144,7 @@ class Host {
     e[0] = 1;
     memcpy(e + 1, &tid, 8);
     e[9] = static_cast<char>(stage);
-    uint64_t t = NowNs();
+    uint64_t t = t_ns ? t_ns : NowNs();
     memcpy(e + 10, &t, 8);
     memcpy(e + 18, &aux, 8);
     SpanAppend(e, 26);
@@ -7532,6 +7577,8 @@ class Host {
   uint64_t fr_now_ms_ = 0;          // per-cycle flight-recorder stamp
   uint64_t last_hist_flush_ms_ = 0;  // hist-delta emission cadence
   uint32_t cur_hash_ = 0;           // current publish's topic hash
+  uint64_t rx_ns_ = 0;              // stamp of the read being ingested
+  std::vector<uint64_t> lane_rx_scratch_;  // LaneDeliver's answered reads
   // @bounded — kind-8 batch (bytes [0,13) = header slot)
   std::string tele_buf_;
   std::string tele_scratch_;  // one sub-record under construction
@@ -7821,9 +7868,11 @@ int emqx_host_set_lane(void* h, int enabled) {
   return static_cast<emqx_native::Host*>(h)->Enqueue(std::move(op));
 }
 
-int emqx_host_lane_deliver(void* h, const uint8_t* blob, size_t len) {
+int emqx_host_lane_deliver(void* h, const uint8_t* blob, size_t len,
+                           uint64_t handed_ns) {
   emqx_native::Op op;
   op.kind = emqx_native::Op::kLaneDeliver;
+  op.token = handed_ns ? handed_ns : emqx_native::NowNs();
   op.str.assign(reinterpret_cast<const char*>(blob), len);
   return static_cast<emqx_native::Host*>(h)->Enqueue(std::move(op));
 }

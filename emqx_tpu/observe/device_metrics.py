@@ -19,10 +19,11 @@ the native plane already uses —
   and the ``GET /api/v5/kernel/stats`` mgmt snapshot;
 - fixed metric slots ``messages.kernel.hostmatch`` /
   ``kernel.uploads`` / ``kernel.upload_patches`` (promoted from the
-  model's ad-hoc counters);
-- span stages ``kernel_submit`` / ``kernel_collect`` for 1-in-N
-  sampled batches into a ``SpanCollector``, so a traced message's
-  timeline no longer has a hole where the TPU was.
+  model's ad-hoc counters).
+
+A sampled message's ``kernel_submit`` / ``kernel_collect`` span points
+come from the native server's lane pump, under the message's own trace
+id (broker/native_server.py ``_lane_time``).
 
 The degradation-ledger reasons (``kernel_overflow`` /
 ``kernel_hostmatch``) fold at the BROKER's publish_batch_collect
@@ -102,21 +103,16 @@ class DeviceMetricsFold:
     torn-but-monotone snapshots, the repo-wide observe discipline.
     """
 
-    def __init__(self, metrics, ledger=None, spans=None, model=None,
-                 node: str = "", sample_every: int = 8) -> None:
+    def __init__(self, metrics, ledger=None, model=None) -> None:
         self.metrics = metrics
         self.ledger = ledger          # kept for symmetry/mgmt; reasons
         #                               fold at the broker seam
-        self.spans = spans            # SpanCollector | None
         self.model = model            # RouterModel | None
-        self.node = node
-        self.sample_every = max(1, int(sample_every))
         self.batches = 0
         self.host_batches = 0
         self.host_topics = 0
         self.last: Optional[KernelCounters] = None
         self.totals: dict[str, int] = {n: 0 for n in KERNEL_COUNTER_FIELDS}
-        self.last_trace_id = 0
         self._synced: dict[str, int] = {}
         # register the stage histograms NOW: fixed stages render at
         # zero in prometheus before the first batch (the render-at-zero
@@ -128,9 +124,8 @@ class DeviceMetricsFold:
 
     # -- model notification seams ------------------------------------------
 
-    def on_batch(self, counters, *, n_topics: int, submit_ns: int,
-                 step_ns: int, decode_ns: int, t_submit_ns: int,
-                 t_collect_ns: int) -> None:
+    def on_batch(self, counters, *, submit_ns: int, step_ns: int,
+                 decode_ns: int) -> None:
         """One device batch collected. ``counters`` is the raw [C] or
         [S, C] block from the shared device_get (None when the model
         was built with kernel_telemetry off)."""
@@ -147,22 +142,6 @@ class DeviceMetricsFold:
                     self.totals[name] = max(self.totals[name], v)
                 else:
                     self.totals[name] += v
-        # trace stitching: every sample_every-th batch (the FIRST one
-        # included, so a single-batch test sees a timeline) mints a
-        # trace id and lands kernel_submit/kernel_collect span points
-        # on the monotonic clock the rest of the span plane uses
-        if self.spans is not None and (self.batches - 1) \
-                % self.sample_every == 0:
-            tid = (int(t_submit_ns) ^ (self.batches << 48)) \
-                & 0xFFFFFFFFFFFFFFFF
-            self.last_trace_id = tid
-            self.spans.record(tid, "kernel_submit", t_submit_ns,
-                              aux=n_topics, node=self.node)
-            self.spans.record(tid, "kernel_collect", t_collect_ns,
-                              aux=n_topics, node=self.node)
-            # hang the submit→collect wall off the step histogram as an
-            # OpenMetrics exemplar — a latency spike links to the trace
-            self._hists["step"].put_exemplar(tid, step_ns)
         self._sync_slots()
 
     def on_host_batch(self, n_topics: int) -> None:
@@ -264,20 +243,4 @@ class DeviceMetricsFold:
             out["last_per_shard"] = {
                 n: self.last.field(n).tolist()
                 for n in KERNEL_COUNTER_FIELDS}
-        return out
-
-    def spans_recent(self, limit: int = 32) -> list[dict]:
-        """Assembled recent kernel traces, JSON-shaped like the native
-        server's spans_recent (the app's default native_spans_fn when
-        no native server is attached)."""
-        if self.spans is None:
-            return []
-        out = []
-        for tid, spans in self.spans.recent(limit):
-            out.append({
-                "trace_id": f"{tid:016x}",
-                "spans": [{"t_ns": t, "stage": s, "shard": sh,
-                           "node": n, "aux": a}
-                          for t, s, sh, n, a in spans],
-            })
         return out

@@ -186,6 +186,15 @@ def hist_bucket(ns: int) -> int:
     return 2 * e - 1 + (1 if (ns << 10) >= (1448 << e) else 0)
 
 
+_EDGES = np.asarray(HIST_EDGES_NS)
+
+
+def hist_buckets(ns: np.ndarray) -> np.ndarray:
+    """``hist_bucket`` over an int64 array (negatives read as 0): a
+    value's bucket is the first whose upper edge lies above it."""
+    return np.searchsorted(_EDGES, np.asarray(ns, np.int64), side="right")
+
+
 class LatencyHistogram:
     """Fixed 64-bucket log-scale latency histogram (sum/count carried
     alongside, prometheus-histogram shaped). Not thread-safe: owners
@@ -210,6 +219,19 @@ class LatencyHistogram:
         self.counts[hist_bucket(ns)] += 1
         self.sum_ns += int(ns)
         self.count += 1
+
+    def observe_n(self, ns: int, n: int) -> None:
+        """``n`` observations of one value (a batch's shared stage)."""
+        self.counts[hist_bucket(ns)] += n
+        self.sum_ns += int(ns) * n
+        self.count += n
+
+    def observe_many(self, ns: np.ndarray) -> None:
+        """One observation per element, in one vectorized fold."""
+        ns = np.maximum(np.asarray(ns, np.int64), 0)
+        self.counts += np.bincount(hist_buckets(ns), minlength=64)
+        self.sum_ns += int(ns.sum())
+        self.count += len(ns)
 
     def observe_delta(self, count_d: int, sum_d: int,
                       bucket_deltas: dict[int, int]) -> None:

@@ -1,8 +1,11 @@
 """Kernel-plane observability (ISSUE 19): in-kernel per-batch counters
 ride the existing collect device_get, the DeviceMetricsFold turns them
 plus the submit/step/decode wall timings into histograms, trie-health
-gauges, ledger reasons and stitched spans — the same surfaces every
-other plane exports through."""
+gauges and ledger reasons — the same surfaces every other plane exports
+through. A sampled lane message's timeline gains the kernel's
+submit/collect points from the lane pump, under the message's own id."""
+
+import asyncio
 
 import numpy as np
 import pytest
@@ -13,7 +16,6 @@ from emqx_tpu.models.router_model import RouterModel
 from emqx_tpu.observe.device_metrics import (KERNEL_COUNTER_FIELDS,
                                              DeviceMetricsFold)
 from emqx_tpu.observe.metrics import Metrics, DegradationLedger
-from emqx_tpu.observe.trace import SpanCollector
 from emqx_tpu.router.index import ShardedTrieIndex, TrieIndex
 
 # a full exact/plus fan: the frontier doubles every level, so K=2
@@ -23,12 +25,10 @@ FAN_FILTERS = ["a/b/c/d", "a/b/c/+", "a/b/+/d", "a/b/+/+",
                "a/+/c/d", "a/+/c/+", "a/+/+/d", "a/+/+/+"]
 
 
-def _fold(model, **kw):
+def _fold(model):
     metrics = Metrics()
     ledger = DegradationLedger(metrics)
-    fold = DeviceMetricsFold(metrics, ledger=ledger,
-                             spans=SpanCollector(), model=model,
-                             node="n1", **kw)
+    fold = DeviceMetricsFold(metrics, ledger=ledger, model=model)
     model.telemetry = fold
     return metrics, ledger, fold
 
@@ -126,32 +126,81 @@ def test_fold_gauges_sharded_skew():
 # -- trace stitching ---------------------------------------------------------
 
 
+def _lane_timelines(shift, n):
+    """``n`` lane publishes through a CPU NativeBrokerServer with the
+    device lane on, every publish in a poll cycle of its own, sampled
+    1-in-2^``shift``; returns (the sampled timelines, traced_pubs)."""
+    from emqx_tpu import native
+    from emqx_tpu.app import BrokerApp
+    from emqx_tpu.broker.native_server import NativeBrokerServer
+    from emqx_tpu.config.config import Config
+    from emqx_tpu.mqtt.client import MqttClient
+
+    if not native.available():
+        pytest.skip("native lib unavailable")
+    conf = Config()
+    conf.put("router.device.enable", True)
+    conf.put("router.device.min_batch", 0)
+    server = NativeBrokerServer(port=0, app=BrokerApp.from_config(conf),
+                                device_lane="on", trace_sample_shift=shift)
+    server.start()
+
+    async def main():
+        sub = MqttClient(port=server.port, clientid="ks")
+        await sub.connect()
+        await sub.subscribe("kt/a", qos=0)
+        pub = MqttClient(port=server.port, clientid="kp")
+        await pub.connect()
+        await pub.publish("kt/a", b"warm", qos=0)    # earns the permit
+        await sub.recv(timeout=20)
+        await asyncio.sleep(0.8)
+        lane0 = server.fast_stats()["lane_out"]
+        for i in range(n):
+            await pub.publish("kt/a", str(i).encode(), qos=0)
+            assert (await sub.recv(timeout=20)).payload == str(i).encode()
+        assert server.fast_stats()["lane_out"] - lane0 == n
+        await asyncio.sleep(0.3)                     # kind-12 spans land
+        await sub.close()
+        await pub.close()
+
+    try:
+        asyncio.run(main())
+        return server.spans_recent(64), server.fast_stats()["traced_pubs"]
+    finally:
+        server.stop()
+
+
+def _in_order(stages, want):
+    it = iter(stages)
+    return all(w in it for w in want)
+
+
 def test_span_stitching_submit_collect():
-    model = RouterModel(TrieIndex(max_levels=8), n_sub_slots=64)
-    model.subscribe("a/b", 1)
-    _metrics, _ledger, fold = _fold(model)
-    _drive(model, ["a/b"])
-    assert fold.last_trace_id != 0
-    stages = fold.spans.stages(fold.last_trace_id)
-    assert stages == ["kernel_submit", "kernel_collect"]
-    spans = fold.spans.trace(fold.last_trace_id)
-    assert spans[0][0] <= spans[1][0]        # monotone timeline
-    # JSON shape matches the native server's spans_recent
-    rec = fold.spans_recent(4)
-    assert rec and rec[0]["trace_id"] == f"{fold.last_trace_id:016x}"
-    assert [s["stage"] for s in rec[0]["spans"]] == stages
-    assert rec[0]["spans"][0]["node"] == "n1"
-    # the sampled batch hung an exemplar on the step histogram
-    assert fold.stage_hists()["step"].exemplars
+    timelines, traced = _lane_timelines(0, 1)
+    assert traced == 1 and len(timelines) == 1
+    spans = timelines[0]["spans"]
+    stages = [s["stage"] for s in spans]
+    # the timeline starts at the socket read, and the kernel's points
+    # sit between it and the route, under the message's own trace id
+    assert stages[0] == "ingress", stages
+    assert _in_order(stages, ["ingress", "kernel_submit",
+                              "kernel_collect", "route"]), stages
+    t = {s["stage"]: s["t_ns"] for s in spans}
+    assert t["ingress"] <= t["kernel_submit"] <= t["kernel_collect"] \
+        <= t["route"]
+    assert {s["aux"] for s in spans
+            if s["stage"].startswith("kernel_")} == {1}   # batch size
 
 
 def test_span_sampling_1_in_n():
-    model = RouterModel(TrieIndex(max_levels=8), n_sub_slots=64)
-    model.subscribe("a/b", 1)
-    _metrics, _ledger, fold = _fold(model, sample_every=4)
-    for _ in range(8):
-        _drive(model, ["a/b"])
-    assert len(fold.spans) == 2              # batches 1 and 5
+    timelines, traced = _lane_timelines(2, 8)
+    # the sampler ticks once per lane park: parks 4 and 8 of 8
+    assert traced == 2 and len(timelines) == 2
+    for tl in timelines:
+        stages = [s["stage"] for s in tl["spans"]]
+        assert stages[0] == "ingress", stages
+        assert _in_order(stages, ["ingress", "kernel_submit",
+                                  "kernel_collect", "route"]), stages
 
 
 # -- broker fallback seam ----------------------------------------------------
@@ -230,8 +279,8 @@ def test_app_wires_fold_and_prometheus_gauges():
     app = _app()
     assert app.device_metrics is not None
     assert app.broker.model.telemetry is app.device_metrics
-    # the kernel fold serves tracing spans until a native server boots
-    assert app.native_spans_fn == app.device_metrics.spans_recent
+    # spans come from a native server's collector alone, none booted yet
+    assert app.native_spans_fn is None
     app.broker.subscribe("c1", "a/b")
     app.broker.publish_batch([Message(topic="a/b")])
     out = app.prometheus()

@@ -338,7 +338,7 @@ while time.time() < deadline:
     for kind, conn, payload in host.poll(20):
         if kind == 4:           # EV_LANE
             with req_lock:
-                lane_reqs.append((conn, payload.decode()))
+                lane_reqs.append((conn, native.parse_lane(payload)[2]))
         elif kind == native.EV_FRAME:
             drained += 1        # punted/drained frames come up verbatim
     st = host.stats()

@@ -98,6 +98,7 @@ def router_step(
             compact_peak=jnp.max(occ),
             overflow_rows=mstats["overflow_rows"],
             trunc_rows=jnp.sum(truncated.astype(jnp.int32)),
+            walk_levels=mstats["walk_levels"],
         )
     if shardings is not None:
         # reshard the compacted fids to dp-only before the tp-sharded OR
@@ -168,6 +169,7 @@ def router_step_sharded(
             compact_peak=jnp.max(occ, axis=1),
             overflow_rows=mstats["overflow_rows"],
             trunc_rows=jnp.sum(trunc.astype(jnp.int32), axis=1),
+            walk_levels=mstats["walk_levels"],
         )
     shard_ids = jnp.arange(S, dtype=per.dtype)[:, None, None]
     per = jnp.where(per >= 0, per * n_shards + shard_ids, -1)

@@ -3,8 +3,9 @@
 The device router computes a compact per-batch counters vector IN the
 routing program (ops/trie_match.py KERNEL_COUNTER_FIELDS — frontier
 peak, probe iterations, candidate counts pre/post-compact, compact-slot
-utilization, overflow/truncation rows) and ships it in the same
-``publish_batch_collect`` device_get as the results — no extra sync.
+utilization, overflow/truncation rows, level iterations walked) and
+ships it in the same ``publish_batch_collect`` device_get as the
+results — no extra sync.
 This module is the host side: it folds those vectors plus the model's
 submit/step/decode wall timings into the SAME observability surfaces
 the native plane already uses —
@@ -50,6 +51,7 @@ KERNEL_COUNTER_FIELDS = (
     "compact_peak",
     "overflow_rows",
     "trunc_rows",
+    "walk_levels",
 )
 
 # per-field fold rule: peaks take max across shards/batches, the rest sum

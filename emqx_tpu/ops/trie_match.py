@@ -9,7 +9,7 @@ tokenized topics against the HBM-resident flat trie of
 Algorithm: K-capped frontier walk. The frontier at step *i* holds the trie
 nodes whose path matches the first *i* topic words (≤K of them; K bounds
 the number of simultaneously-alive wildcard branches, overflow is reported
-so the host oracle can take over for that topic). Each scan step does:
+so the host oracle can take over for that topic). Each level step does:
 
 1. emit ``hash_fid`` of every frontier node (a ``prefix/#`` filter matches
    any remaining suffix, including the empty one);
@@ -20,9 +20,13 @@ so the host oracle can take over for that topic). Each scan step does:
 Every matching filter id is emitted exactly once per topic (tree-ness of
 the trie — see index.py), so the output needs masking but no dedup.
 
-All control flow is static (lax.scan over L+1 steps, unrolled probe loop):
-no data-dependent shapes, everything fuses into gathers + elementwise ops —
-HBM-bandwidth-bound, which is the right regime for this workload.
+The level loop runs ``max(lengths) + 1`` steps, bounded on the device by
+the batch's deepest topic: a level past every topic's end emits nothing
+and leaves an empty frontier, so skipping it changes no output. Each
+step writes its emits into preallocated ``[L+1, B, K]`` buffers (-1
+where no step ran), so shapes stay static and one compiled program
+serves every depth. The probe loop unrolls; everything fuses into
+gathers + elementwise ops.
 
 Pallas note (evaluated, intentionally not used here): every hot op in this
 kernel is a scattered row/element gather from HBM-resident tables indexed
@@ -30,8 +34,10 @@ by data-dependent lanes. Pallas-TPU expresses gathers as either per-block
 DMAs (grid step per row — B·K·probes steps ≈ 10^6 latency-bound DMAs per
 batch) or VMEM-resident tables (the 1M-filter trie is ~25MB+, over VMEM).
 XLA's native gather lowering with the optimization-barrier placement below
-is the fast path (measured: 0.03ms/batch at 1M filters); the pipeline-level
-win instead comes from overlapping dispatch (see bench.py window).
+is the fast path. Device time of one B=64 step on a TPU v5e at 1M exact
+2-level filters (benchmark ``kernel_ms.open``): 8.67 ms with the fixed
+17-level walk, 1.68 ms with the walk bounded by the deepest topic; a
+batch holding a 16-level topic still walks all 17 levels.
 """
 
 from __future__ import annotations
@@ -46,7 +52,7 @@ import numpy as np
 from emqx_tpu.router.index import HASH_ID, PAD, TrieIndexArrays
 
 # plain Python ints: module-level jnp scalars are concrete device arrays,
-# and closure-captured device arrays inside a scan body hit a catastrophic
+# and closure-captured device arrays inside a loop body hit a catastrophic
 # slow path on TPU (measured ~400ms vs 0.03ms for the same probe loop)
 _MIX_A = 0x9E3779B1
 _MIX_B = 0x85EBCA77
@@ -67,6 +73,7 @@ KERNEL_COUNTER_FIELDS = (
     "compact_peak",    # max per-topic compact-slot occupancy (M utilization)
     "overflow_rows",   # topics whose K frontier spilled (incomplete match)
     "trunc_rows",      # topics truncated by the M compact
+    "walk_levels",     # level iterations the walk ran: max(lengths) + 1
 )
 
 
@@ -161,7 +168,8 @@ def _probe_exact(
     # hash the raw parent (-1 included): indices stay in-bounds via the
     # mask, invalid lanes are killed by `done`, and the obvious
     # where-clamp here triggers an XLA-TPU lowering cliff (~5× slower —
-    # a select feeding a gather's index chain inside scan de-vectorizes)
+    # a select feeding a gather's index chain inside the level loop
+    # de-vectorizes)
     h = _edge_hash(parent, word, hmask)
     step = _edge_step(parent, word, hmask)
     child = jnp.full_like(parent, -1)
@@ -208,25 +216,32 @@ def match_batch(
     -1 elsewhere.  ``overflow[b]`` means topic *b*'s frontier exceeded K
     and the result may be incomplete — route it through the host oracle.
     ``mstats`` is the match half of the kernel counters (scalar int32
-    leaves: frontier_peak / probe_iters / cand_pre / overflow_rows —
-    see KERNEL_COUNTER_FIELDS); the compact-side fields are the step
-    functions' (router_model) to fill.  The reductions are elementwise
-    and ride the same program — XLA DCEs them when the caller drops the
-    dict.
+    leaves: frontier_peak / probe_iters / cand_pre / overflow_rows /
+    walk_levels — see KERNEL_COUNTER_FIELDS); the compact-side fields
+    are the step functions' (router_model) to fill.  The reductions are
+    elementwise and ride the same program — XLA DCEs them when the
+    caller drops the dict.
     """
     B, L = tokens.shape
-    tokens_ext = jnp.concatenate(
+    # level-major, so each iteration reads its level with one dynamic
+    # row index
+    tokens_lv = jnp.concatenate(
         [tokens, jnp.full((B, 1), PAD, tokens.dtype)], axis=1
-    )
+    ).T                                                  # [L+1, B]
+    # levels past the batch's deepest topic emit nothing and leave an
+    # all -1 frontier, so the walk stops there; the clamp keeps the
+    # bound inside the [L+1] buffers for any lengths
+    n_levels = jnp.minimum(jnp.max(lengths, initial=0), L) + 1
 
     frontier0 = jnp.full((B, K), -1, jnp.int32).at[:, 0].set(0)  # root
     overflow0 = jnp.zeros((B,), bool)
     peak0 = jnp.zeros((), jnp.int32)
     probes0 = jnp.zeros((), jnp.int32)
+    ems0 = jnp.full((L + 1, B, K), -1, jnp.int32)
 
-    def step(carry, xs):
-        frontier, overflow, peak, probes = carry
-        i, tok = xs                               # i scalar, tok [B]
+    def step(i, carry):
+        frontier, overflow, peak, probes, hash_ems, end_ems = carry
+        tok = jax.lax.dynamic_index_in_dim(tokens_lv, i, keepdims=False)
         valid = frontier >= 0
         peak = jnp.maximum(
             peak, jnp.max(jnp.sum(valid.astype(jnp.int32), axis=1)))
@@ -252,12 +267,13 @@ def match_batch(
         nxt, over = _pack_frontier(
             jnp.concatenate([exact, plus], axis=1), K
         )
-        return (nxt, overflow | over, peak, probes), (hash_em, end_em)
+        hash_ems = jax.lax.dynamic_update_index_in_dim(hash_ems, hash_em, i, 0)
+        end_ems = jax.lax.dynamic_update_index_in_dim(end_ems, end_em, i, 0)
+        return nxt, overflow | over, peak, probes, hash_ems, end_ems
 
-    (_, overflow, peak, probes), (hash_ems, end_ems) = jax.lax.scan(
-        step,
-        (frontier0, overflow0, peak0, probes0),
-        (jnp.arange(L + 1), tokens_ext.T),
+    _, overflow, peak, probes, hash_ems, end_ems = jax.lax.fori_loop(
+        0, n_levels, step,
+        (frontier0, overflow0, peak0, probes0, ems0, ems0),
     )
     # [L+1, B, K] → [B, (L+1)*K] each → concat
     cand = jnp.concatenate(
@@ -272,6 +288,7 @@ def match_batch(
         "probe_iters": probes,
         "cand_pre": jnp.sum((cand >= 0).astype(jnp.int32)),
         "overflow_rows": jnp.sum(overflow.astype(jnp.int32)),
+        "walk_levels": n_levels,
     }
     return cand, overflow, mstats
 

@@ -46,6 +46,19 @@ def test_pack_decode_round_trip_sharded():
     assert kc.value("probe_iters") == int(vals["probe_iters"].sum())
 
 
+def test_walk_levels_declared_in_both_and_summed():
+    # the level-loop bound's counter, in both declarations (their
+    # parity is test_counter_fields_parity's), folds as a sum over
+    # shards, not a peak
+    assert "walk_levels" in tm.KERNEL_COUNTER_FIELDS
+    assert "walk_levels" not in dm._PEAK_FIELDS
+    vals = {n: np.zeros(2, np.int32) for n in tm.KERNEL_COUNTER_FIELDS}
+    vals["walk_levels"] = np.array([3, 3], np.int32)
+    kc = dm.KernelCounters(tm.pack_counters(**vals))
+    assert kc.field("walk_levels").tolist() == [3, 3]
+    assert kc.value("walk_levels") == 6
+
+
 def test_pack_counters_rejects_drifted_field_set():
     vals = {n: 1 for n in tm.KERNEL_COUNTER_FIELDS}
     with pytest.raises(TypeError):
@@ -88,6 +101,8 @@ def test_kernel_counters_sane_batch():
     assert st["frontier_peak"] >= 2
     # every resolved exact edge costs at least one probe iteration
     assert st["probe_iters"] >= 1
+    # the deepest topic has 3 levels: the walk ran levels 0..3
+    assert st["walk_levels"] == 4
 
 
 def test_kernel_counters_overflow_rows():

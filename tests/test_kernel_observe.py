@@ -61,10 +61,15 @@ def test_fold_counters_and_stage_hists_flat():
     assert fold.batches == 2
     assert fold.totals["cand_pre"] == 3 + 2   # sums across batches
 
+    # walk_levels sums over batches: 4 levels twice, then 3
+    _drive(model, ["d/e"])
+    assert fold.last.value("walk_levels") == 3
+    assert fold.totals["walk_levels"] == 4 + 4 + 3
+
     ks = fold.kernel_summary()
-    assert ks["batches"] == 2
+    assert ks["batches"] == 3
     assert set(ks["counters"]) == set(KERNEL_COUNTER_FIELDS)
-    assert ks["stages"]["step"]["count"] == 2
+    assert ks["stages"]["step"]["count"] == 3
 
 
 def test_fold_truncation_counter():
@@ -121,6 +126,9 @@ def test_fold_gauges_sharded_skew():
     _drive(model, ["s/3/x", "s/7/x"])
     assert fold.last.n_shards == 4
     assert int(fold.last.field("cand_pre").sum()) == 2
+    # every shard walks the same 4 levels; the fold sums the shards
+    assert fold.last.field("walk_levels").tolist() == [4] * 4
+    assert fold.totals["walk_levels"] == 16
 
 
 # -- trace stitching ---------------------------------------------------------

@@ -353,6 +353,23 @@ def test_warm_compiles_every_serving_program():
     assert cc.n == 0
 
 
+def test_warm_to_the_top_bucket_compiles_only_the_serving_set():
+    """The walk's level bound is a device value: warming to the lane's
+    top bucket compiles one step program per batch bucket and the
+    patch buckets, nothing per topic depth."""
+    from emqx_tpu.models import router_model as rm
+
+    m = make_model()
+    seconds = m.warm(16384)
+    assert set(seconds) == (
+        {f"step/{64 << k}" for k in range(9)}
+        | {f"patch/{c}" for c in rm.PATCH_BUCKETS})
+    with _CompileCounter() as cc:
+        for topic in ("a", "a/b/c", "a/b/c/d/e/f/g/h"):
+            m.publish_batch_submit([topic] * 3, compiled_only=True)
+    assert cc.n == 0
+
+
 def test_compiled_only_submit_refuses_cold_programs():
     """A batch past the warmed buckets, or tables that grew since the
     warm, raise ColdTables before any launch; warm() clears it."""

@@ -10,6 +10,7 @@ import os
 
 import numpy as np
 
+import readers
 import reference
 import registry
 import stats
@@ -80,6 +81,42 @@ def stall_line(out: dict, late) -> str:
             f"{stood}; sends more than 200 ms late: {span}")
 
 
+def lane_hists(out: dict) -> dict:
+    """The window's delta of each lane histogram, by histogram name."""
+    s, e = out["start"]["hists"], out["end"]["hists"]
+    return {k: stats.hist_delta(s[k], e[k]) for k in readers.LANE_HISTS}
+
+
+def segments_line(hists: dict) -> str:
+    """The six lane segments' means, their sum and the whole's mean, in
+    ms per lane message, with the count of messages each observed."""
+    means = {name: readers.hist_mean_ms(hists[h])
+             for name, h in readers.LANE_SEGMENTS.items()}
+    parts = ", ".join(f"{name[:-len('_mean_ms')]} {v} "
+                      f"({hists[h]['count']})" for (name, h), v in
+                      zip(readers.LANE_SEGMENTS.items(), means.values()))
+    known = [v for v in means.values() if v is not None]
+    total = hists[readers.LANE_TOTAL]
+    return (f"lane segments, mean ms per message (messages): {parts}; sum "
+            f"{sum(known) if len(known) == len(means) else None}; "
+            f"lane_total {readers.hist_mean_ms(total)} ({total['count']})")
+
+
+def start_line(out: dict, late) -> str:
+    """How late the sends of the window's first 200 ms left, beside the
+    latest send of the whole window and when it was due."""
+    t0 = out["t0"]
+    first = out["sched"] < t0 + 200_000_000
+    if not len(late):
+        return "window start: no sends"
+    k = int(np.argmax(late))
+    head = float(late[first].max()) / 1e6 if first.any() else None
+    return (f"window start: {int(first.sum())} sends in the first 200 ms, "
+            f"lateness max {head} ms; the window's latest send "
+            f"{float(late[k]) / 1e6} ms late, due at "
+            f"{(int(out['sched'][k]) - t0) / 1e9:.3f} s")
+
+
 def _peak(kind: str):
     def get():
         with open(os.path.join(HERE, "peaks.json")) as f:
@@ -128,6 +165,10 @@ def evaluate(cell: dict, out: dict, seconds: float, traced: bool,
                  f"{not any(out['lane'].values())} {out['lane']}")
     lines.append(f"windows void before this one (lane counters): "
                  f"{len(out['voided'])} {out['voided']}")
+    lines.append("warm windows before this one (s from its start, "
+                 "length s, sends, lane counters moved): " + str(
+                     [(round((w["t0"] - out["t0"]) / 1e9, 3), w["seconds"],
+                       w["sent"], w["lane"]) for w in out["warm"]]))
 
     computed = {"setup_s": out["setup_s"]}
     # a delivery that never came counts the whole wait for it
@@ -164,6 +205,9 @@ def evaluate(cell: dict, out: dict, seconds: float, traced: bool,
              if e["stats"][k] != s["stats"].get(k, 0)}
     lines.append(f"host counters moved in the window: {moved}")
     lines.append(stall_line(out, late))
+    lines.append(start_line(out, late))
+    hists = lane_hists(out)
+    lines.append(segments_line(hists))
     gcs = out["gc"]
     lines.append(f"gc in the window: broker {gcs['n']} pauses, "
                  f"{gcs['total_ms']:.3f} ms total, {gcs['max_ms']:.3f} ms "
@@ -180,8 +224,7 @@ def evaluate(cell: dict, out: dict, seconds: float, traced: bool,
     result = {"correct": correct, "attempted": n_pub,
               "failed": d["failed_msgs"], "metrics": metrics}
     if traced:
-        ctx = {"gen_late_ns": late,
-               "dwell": stats.hist_delta(s["dwell"], e["dwell"]),
+        ctx = {"gen_late_ns": late, "lane_hists": hists,
                "trace": out["trace"], "peak": _peak(device.device_kind),
                "bytes_per_launch": roofline_bytes(out)}
         for m in cell["per_layer"]:

@@ -8,7 +8,8 @@ window delivers is compared with the plain reference (``reference``):
 every delivery due, to exactly the subscribers whose filters match, with
 every byte intact. The broker's counters show whether the device lane
 served every publish of the window; a window it did not serve is void
-and is measured once more (``run``).
+and is measured once more (``run``). Before each measured window a warm
+window sends the cell's own traffic unmeasured (``Session.warm_window``).
 """
 
 from __future__ import annotations
@@ -22,10 +23,12 @@ import time
 import numpy as np
 
 import loadgen
+import readers
 import reference
 import registry
 
 DRAIN_S = 60.0          # how long after the window a due delivery may come
+WARM_S = 5.0            # the warm window before each measured one
 TRACE_S = 5.0           # the traced part of a --trace 1 window (its end)
 STALL_S = 3.5           # a planted pump stall, past the lane's 3 s deadline
 METRICS = ("messages.device_failover", "messages.kernel.hostmatch")
@@ -239,9 +242,8 @@ class LaneTap:
 def hist_snapshot(metrics, name: str) -> dict:
     h = metrics.hist(name)
     if h is None:
-        return {"counts": np.zeros(64, np.int64), "count": 0, "sum_ns": 0}
-    return {"counts": np.array(h.counts, np.int64), "count": int(h.count),
-            "sum_ns": int(h.sum_ns)}
+        return {"count": 0, "sum_ns": 0}
+    return {"count": int(h.count), "sum_ns": int(h.sum_ns)}
 
 
 def snapshot(server, app, model) -> dict:
@@ -249,7 +251,8 @@ def snapshot(server, app, model) -> dict:
     return {"stats": {k: int(v) for k, v in st.items()},
             "metrics": {k: int(app.metrics.val(k)) for k in METRICS},
             "launches": int(model.launch_count),
-            "dwell": hist_snapshot(app.metrics, "latency.native.lane_dwell")}
+            "hists": {k: hist_snapshot(app.metrics, k)
+                      for k in readers.LANE_HISTS}}
 
 
 def lane_counters(start: dict, end: dict, n_pub: int) -> dict:
@@ -271,14 +274,16 @@ def lane_counters(start: dict, end: dict, n_pub: int) -> dict:
 def run(cell: dict, seed: int, seconds: float, traced: bool, *,
         t_proc: float, layout: dict, chip: bool = True,
         fault: str = None) -> dict:
-    """Set-up and one window. A window the device lane did not serve in
-    full (a stall of the whole host past the lane's 3 s deadline hands
-    it to the broker's host path) is void: none of its numbers is
-    reported. The lane reopens, the permits are earned again and one
-    more window is measured; if that one is not served either, its lane
-    counters fail the run's checks."""
+    """Set-up, the warm window and one measured window. A window the
+    device lane did not serve in full (a stall of the whole host past
+    the lane's 3 s deadline hands it to the broker's host path) is void:
+    none of its numbers is reported. The lane reopens, the permits are
+    earned again, the warm window runs again and one more window is
+    measured; if that one is not served either, its lane counters fail
+    the run's checks."""
     with Session(cell, seed, t_proc=t_proc, layout=layout, chip=chip,
                  traced=traced, fault=fault) as s:
+        s.warm_window(WARM_S)
         out = s.window(seconds, traced)
         if not any(out["lane"].values()):
             return out
@@ -287,8 +292,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool, *,
         s.reopen_lane()
         log(f"lane reopened and permits earned again in "
             f"{time.monotonic() - t:.3f} s")
+        s.warm_window(WARM_S)
         again = s.window(seconds, traced)
-        again["setup_s"] = out["setup_s"]
         again["voided"] = [out["lane"]]
         return again
 
@@ -306,6 +311,8 @@ class Session:
         self.ph = Phases(t_proc)
         self.procs: list = []
         self.server = None
+        self.t_first = None     # the session's first scheduled send, ns
+        self.warm: list[dict] = []
 
     def __enter__(self) -> "Session":
         try:
@@ -450,11 +457,8 @@ class Session:
         for c in self.pubs:
             c.send(("warm",))
         _wait(self.pubs, "permit warm-up")
-        deadline = time.monotonic() + 120
-        while self._count(1) < due:
-            if time.monotonic() > deadline:
-                raise TimeoutError("permit warm-up deliveries missing")
-            time.sleep(0.01)
+        if self._drain(1, due, 120):
+            raise TimeoutError("permit warm-up deliveries missing")
         deadline = time.monotonic() + 20
         while (_granted(self.server) < self.pairs
                and time.monotonic() < deadline):
@@ -482,6 +486,66 @@ class Session:
         server._last_permit_flush = time.monotonic()
         self.warm_permits()
 
+    def _drain(self, slot: int, due: int, timeout: float) -> int:
+        """Wait until counter ``slot`` (0: window deliveries, 1: marked
+        ones) reaches ``due``, at most ``timeout`` s; returns how many
+        are still missing."""
+        deadline = time.monotonic() + timeout
+        while self._count(slot) < due and time.monotonic() < deadline:
+            time.sleep(0.01)
+        return max(0, due - self._count(slot))
+
+    def _due(self, sent: list) -> tuple:
+        """The topic indices a window's publishers report and the
+        deliveries they make due."""
+        tidx = np.concatenate([np.frombuffer(s["tidx"], np.int64)
+                               for s in sent])
+        subs_of = self.inputs["subs_of"]
+        return tidx, int(sum(len(subs_of[t]) for t in tidx.tolist()))
+
+    def _go(self, seconds: float, rate: float, warm: bool) -> int:
+        """Start the publishers' open loop 200 ms from now; returns its
+        first scheduled send (ns)."""
+        t0 = time.monotonic_ns() + 200_000_000
+        if self.t_first is None:
+            self.t_first = t0
+        for c in self.pubs:
+            c.send(("go", t0, seconds, rate, warm))
+        return t0
+
+    def warm_window(self, seconds: float, **params) -> None:
+        """The cell's own traffic for ``seconds``, unmeasured, right
+        before a measured window: the first window of a process held a
+        send hiccup and the lane's first batches (PERF.md). Its messages
+        carry the warm-up mark, so the receivers count them apart and
+        record and check nothing of them; it drains before the measured
+        window's snapshots, GC clock and lane tap start. The permits'
+        re-earn clock (``PERMIT_TTL_S``) restarts once it has drained,
+        so the measured window ends before the next re-earn. A warm
+        window the lane did not serve (a host stall past its deadline)
+        is run once more after the lane has reopened."""
+        tr = dict(self.cell["traffic"], **params)
+        for attempt in range(2):
+            st0 = self.server.fast_stats()
+            warm0 = self._count(1)
+            t0 = self._go(seconds, tr["rate_per_s"], warm=True)
+            sent = _wait(self.pubs, "warm window", timeout=seconds + 120)
+            tidx, due = self._due(sent)
+            missing = self._drain(1, warm0 + due, DRAIN_S)
+            if missing:
+                raise TimeoutError(
+                    f"warm window: {missing} deliveries missing")
+            st1 = self.server.fast_stats()
+            tripped = {k: int(st1[k]) - int(st0[k]) for k in LANE_STATS}
+            self.warm.append({"t0": t0, "seconds": seconds,
+                              "sent": len(tidx), "lane": tripped})
+            if attempt or not any(tripped.values()):
+                break
+            log(f"warm window not served by the lane: {tripped}")
+            self.reopen_lane()
+        time.sleep(0.3)         # its telemetry deltas fold in
+        self.server._last_permit_flush = time.monotonic()
+
     def window(self, seconds: float, traced: bool,
                trace_s: float = TRACE_S, **params) -> dict:
         """One measured window; ``params`` override the traffic's
@@ -490,15 +554,12 @@ class Session:
 
         tr = dict(self.cell["traffic"], **params)
         server, app, model, tap = self.server, self.app, self.model, self.tap
-        subs_of = self.inputs["subs_of"]
         gcc = loadgen.GcClock()
         start = snapshot(server, app, model)
         recv0 = self._count(0)
         tap.start_window()
-        t0 = time.monotonic_ns() + 200_000_000
-        setup_s = t0 / 1e9 - self.ph.t_proc
-        for c in self.pubs:
-            c.send(("go", t0, seconds, tr["rate_per_s"]))
+        t0 = self._go(seconds, tr["rate_per_s"], warm=False)
+        setup_s = self.t_first / 1e9 - self.ph.t_proc
         for c in self.recv:
             c.send("reset_gc")
         gcc.reset()
@@ -531,10 +592,7 @@ class Session:
             [np.frombuffer(s[k], np.int64) for s in sent])
         msg, tidx, sched, sent_t = (cat("msg"), cat("tidx"), cat("sched"),
                                     cat("sent"))
-        due = recv0 + int(sum(len(subs_of[t]) for t in tidx.tolist()))
-        deadline = time.monotonic() + DRAIN_S
-        while self._count(0) < due and time.monotonic() < deadline:
-            time.sleep(0.01)
+        self._drain(0, recv0 + self._due(sent)[1], DRAIN_S)
         time.sleep(0.5)         # a stray or duplicate delivery shows by now
         drained_ns = time.monotonic_ns()
         gc_window = gcc.as_dict()
@@ -568,6 +626,7 @@ class Session:
             "trace": trace_red,
             "memory_peak_bytes": int(stats_dev.get("peak_bytes_in_use", 0)),
             "granted": self.granted, "pairs": self.pairs,
+            "warm": list(self.warm),
             "permit_age_s": (time.monotonic()
                              - server._last_permit_flush),
             "inputs": self.inputs,

@@ -14,7 +14,9 @@ the scheduled send time to the read at the subscriber's socket; both
 clocks are the host's CLOCK_MONOTONIC, which is system-wide.
 
 Shared counters (one int64 array): slot ``2*r`` counts receiver
-``r``'s window deliveries, ``2*r+1`` its warm-up deliveries.
+``r``'s window deliveries, ``2*r+1`` its deliveries of messages with
+the warm-up mark (``WARM_BIT``: the permit warm-up's and the warm
+windows'), which it neither records nor checks.
 """
 
 from __future__ import annotations
@@ -286,8 +288,8 @@ def receiver_main(ctl, spec: dict, counters) -> None:
 def publisher_main(ctl, spec: dict) -> None:
     """Own ``spec["conns"]`` publisher connections, each with its topic
     pool; warm every (connection, topic) permit, then run each window's
-    open loop at the rate it is given and report what was sent and
-    when."""
+    open loop (a warm window's or a measured one's) at the rate it is
+    given and report what was sent and when."""
     _enter(spec.get("cpus"))
     gcc = GcClock()
     topics = spec["topics"]
@@ -313,11 +315,11 @@ def publisher_main(ctl, spec: dict) -> None:
                 s.sendall(b"".join(out))
             ctl.send(("warmed", n))
         elif cmd[0] == "go":
-            _, t0, seconds, rate = cmd
+            _, t0, seconds, rate, warm = cmd
             gcc.reset()
             sw0 = _switches()
             res = _open_loop(dict(spec, rate_per_s=rate), socks, pools,
-                             prefix, t0, seconds)
+                             prefix, t0, seconds, warm)
             sw1 = _switches()
             res["gc"] = dict(gcc.as_dict(), **{
                 f"{k}_switches": sw1[k] - sw0[k] for k in sw1})
@@ -352,9 +354,10 @@ def open_schedule(spec: dict, n_pools: int, seconds: float):
     return off, conn, pos
 
 
-def _open_loop(spec, socks, pools, prefix, t0, seconds) -> dict:
+def _open_loop(spec, socks, pools, prefix, t0, seconds, warm) -> dict:
+    """One window's sends; a warm window's carry the warm-up mark."""
     size = spec["payload_bytes"]
-    base = spec["proc"] << PROC_SHIFT
+    base = (spec["proc"] << PROC_SHIFT) | (WARM_BIT if warm else 0)
     off, conn, pos = open_schedule(spec, len(pools), seconds)
     n = len(off)
     tidx = np.empty(n, np.int64)

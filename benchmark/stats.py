@@ -1,5 +1,5 @@
 """Arithmetic the benchmark owns: sample percentiles and the windowed
-delta of the broker's log-bucket histograms."""
+delta of the broker's histograms."""
 
 from __future__ import annotations
 
@@ -15,47 +15,8 @@ def percentile(values, q: float) -> float:
     return float(np.quantile(a, q))
 
 
-# The broker's latency histograms (the C++ telemetry plane) bucket at
-# powers of sqrt(2): 64 buckets, bucket 0 = [0, 2) ns; for bit position
-# e >= 1, bucket 2e-1 tops at 1448/1024 * 2^e and bucket 2e at 2^(e+1);
-# bucket 63 is unbounded. The edges are copied so the benchmark's
-# percentile arithmetic cannot move with the program's.
-def _hist_edges() -> tuple:
-    edges = [2.0]
-    for e in range(1, 32):
-        edges.append((1448 << e) / 1024.0)
-        edges.append(float(1 << (e + 1)))
-    return tuple(edges + [float("inf")])
-
-
-HIST_EDGES_NS = _hist_edges()
-
-
 def hist_delta(start: dict, end: dict) -> dict:
-    """Window delta of two snapshots ``{"counts", "count", "sum_ns"}``."""
-    counts = (np.asarray(end["counts"], np.int64)
-              - np.asarray(start["counts"], np.int64))
-    return {"counts": counts, "count": int(end["count"] - start["count"]),
+    """Window delta of two histogram snapshots ``{"count", "sum_ns"}``:
+    the observations and their exact sum in ns between the two."""
+    return {"count": int(end["count"] - start["count"]),
             "sum_ns": int(end["sum_ns"] - start["sum_ns"])}
-
-
-def hist_percentile(delta: dict, q: float):
-    """q-quantile in ns of a bucketed delta, linear inside the bucket;
-    None when the window observed nothing."""
-    counts = delta["counts"]
-    total = int(counts.sum())
-    if total <= 0:
-        return None
-    target = q * total
-    cum = 0
-    for i, c in enumerate(counts.tolist()):
-        if c <= 0:
-            continue
-        prev, cum = cum, cum + c
-        if cum >= target:
-            lo = HIST_EDGES_NS[i - 1] if i else 0.0
-            hi = HIST_EDGES_NS[i]
-            if hi == float("inf"):
-                return lo
-            return lo + (hi - lo) * max(0.0, target - prev) / c
-    return None

@@ -10,10 +10,16 @@ traffic files and ``PERF.md`` record.
 The broker re-earns every publish permit every 60 s
 (``PERMIT_TTL_S``); a sweep outlives that, so before each window the
 tool restarts that clock and publishes every (publisher, topic) pair
-once more, which earns again any permit the last flush took. A
-benchmark run does so only before the window that replaces a void one
-(``harness.run``): its first window ends well inside the first 60 s of
-the broker.
+once more, which earns again any permit the last flush took (the first
+window's were earned in set-up). Each rate's window follows a warm
+window at that rate, as a benchmark run's does
+(``harness.Session.warm_window``); ``--warm-seconds 0`` leaves it out,
+as the benchmark did before it had one.
+
+For a study of the spread between windows, repeat one rate:
+
+    python3 benchmark/sweep.py --workload p2p_1m.knee80 --seed 7 \
+        --loads 2000,2000,2000,2000 --seconds 30 --trace-seconds 0
 """
 
 from __future__ import annotations
@@ -38,7 +44,11 @@ def main() -> None:
     ap.add_argument("--loads", required=True,
                     help="offered msgs/s, comma-separated")
     ap.add_argument("--seconds", type=float, default=15.0)
-    ap.add_argument("--trace-seconds", type=float, default=3.0)
+    ap.add_argument("--trace-seconds", type=float, default=3.0,
+                    help="the traced window after each rate; 0: none")
+    ap.add_argument("--warm-seconds", type=float, default=None,
+                    help="the warm window before each rate's; 0: none "
+                    "(default: the benchmark's)")
     args = ap.parse_args()
     for p in (HERE, ROOT):
         if p not in sys.path:
@@ -53,34 +63,38 @@ def main() -> None:
     import run
     import numpy as np
 
+    import readers
     import stats
 
     run.enable_compile_cache()
+    warm_s = harness.WARM_S if args.warm_seconds is None else args.warm_seconds
     rows = []
     with harness.Session(cell, args.seed, t_proc=T_PROC, layout=layout,
-                         traced=True) as s:
+                         traced=args.trace_seconds > 0) as s:
         key = "rate_per_s"
-        for load in [float(x) for x in args.loads.split(",")]:
-            s.server._last_permit_flush = time.monotonic()
-            s.warm_permits()
+        for i, load in enumerate(float(x) for x in args.loads.split(",")):
+            if i:
+                s.server._last_permit_flush = time.monotonic()
+                s.warm_permits()
+            if warm_s > 0:
+                s.warm_window(warm_s, **{key: load})
             out = s.window(args.seconds, False, **{key: load})
             res, lines, checks = evaluate.evaluate(
                 cell, out, args.seconds, False, s.devices[0])
-            for line in lines[:4]:
+            for line in lines:
                 harness.log(f"[{load}] {line}")
             d = evaluate.deliveries(out)
             in_window = int(((d["arrive"] >= 0)
                              & (d["arrive"] <= out["t_end"])).sum())
             late = out["sent"] - out["sched"]
-            dwell = stats.hist_percentile(
-                stats.hist_delta(out["start"]["dwell"], out["end"]["dwell"]),
-                0.95)
-            s.server._last_permit_flush = time.monotonic()
-            s.warm_permits()
-            tw = s.window(args.trace_seconds + 0.5, True,
-                          trace_s=args.trace_seconds, **{key: load})
-            t = tw["trace"] or {"busy_s": 0.0, "window_s": 1.0,
-                                "step_launches": 0}
+            hists = evaluate.lane_hists(out)
+            t = None
+            if args.trace_seconds > 0:
+                s.server._last_permit_flush = time.monotonic()
+                s.warm_permits()
+                tw = s.window(args.trace_seconds + 0.5, True,
+                              trace_s=args.trace_seconds, **{key: load})
+                t = tw["trace"]
             sizes = np.asarray(out["sizes"], np.int64)
             row = {
                 key: load,
@@ -88,16 +102,26 @@ def main() -> None:
                 "max_ms": (float(d["lat_ns"][d["found"]].max()) / 1e6
                            if d["found"].any() else None),
                 "delivered_over_offered": in_window / max(1, d["due"]),
-                "idle_pct": 100.0 * (1 - t["busy_s"] / t["window_s"]),
+                "idle_pct": (100.0 * (1 - t["busy_s"] / t["window_s"])
+                             if t else None),
                 "step_ms": (t["step_device_s"] / t["step_launches"] * 1e3
-                            if t["step_launches"] else None),
+                            if t and t["step_launches"] else None),
                 "lane_batch_mean": float(sizes.mean()) if len(sizes) else 0,
                 "lane_batch_buckets": {
                     int(b): int(n) for b, n in zip(*np.unique(
                         np.maximum(64, 2 ** np.ceil(np.log2(
                             np.maximum(sizes, 1)))), return_counts=True))},
-                "lane_dwell_p95_ms": None if dwell is None else dwell / 1e6,
+                **{name: readers.hist_mean_ms(hists[h])
+                   for name, h in readers.LANE_SEGMENTS.items()},
+                "lane_total_mean_ms": readers.hist_mean_ms(
+                    hists[readers.LANE_TOTAL]),
+                "launches": out["end"]["launches"]
+                - out["start"]["launches"],
+                "gc_broker": out["gc"],
+                "permit_age_s": out["permit_age_s"],
                 "gen_late_p95_ms": stats.percentile(late, 0.95) / 1e6,
+                "first_200ms_late_max_ms": float(late[
+                    out["sched"] < out["t0"] + 200_000_000].max()) / 1e6,
                 "correct": res["correct"],
                 "checks": {k: v["value"] for k, v in res["checks"].items()
                            if v["value"]},
@@ -113,6 +137,7 @@ def main() -> None:
     print("|" + "---|" * len(cols))
     for r in rows:
         print("| " + " | ".join(str(r.get(c)) for c in cols) + " |")
+
 
 if __name__ == "__main__":
     main()

@@ -1,23 +1,24 @@
 """CPU self-tests of the benchmark's own code: the trace reduction, the
 roofline byte count, the plain reference matcher, the windowed histogram
-delta, finding parts by name, and one run of the harness through the
-served path at a small size with its result line and its failures.
+delta and the lane segment readers, finding parts by name, and runs of
+the harness through the served path at a small size: its result line,
+its warm window and its failures.
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
 
 from __future__ import annotations
 
-import copy
 import json
 import os
 import random
 
-import numpy as np
 import pytest
 
 import evaluate
 import harness
+import loadgen
+import readers
 import reference
 import registry
 import stats
@@ -67,6 +68,34 @@ def test_trace_reduce_synthetic():
     assert gaps["lane.collect"] == pytest.approx(0.040)
     assert gaps["no_span"] == pytest.approx(0.015)
     assert sum(gaps.values()) + r["busy_s"] == pytest.approx(r["window_s"])
+
+
+def test_trace_gap_under_the_pumps_gate():
+    """A gap inside the pump's ``lane.gate`` is charged to it, one inside
+    a ``lane.collect`` nested in that gate to the collect, one inside
+    ``lane.idle`` or ``lane.respond`` to those."""
+    ms = 1_000_000
+    tr = {
+        "/host:CPU": {
+            "main": [("bench.window", 0, 100 * ms)],
+            "pump": [("lane.idle", 0, 10 * ms),
+                     ("lane.gate", 20 * ms, 45 * ms),
+                     ("lane.collect", 22 * ms, 6 * ms),
+                     ("lane.respond", 70 * ms, 20 * ms)],
+        },
+        "/device:TPU:0": {
+            "XLA Ops": [("fusion.1", 10 * ms, 10 * ms),
+                        ("fusion.1", 30 * ms, 30 * ms),
+                        ("fusion.1", 62 * ms, 8 * ms),
+                        ("fusion.1", 90 * ms, 10 * ms)],
+        },
+    }
+    # gaps: 0-10 (mid 5: idle), 20-30 (mid 25: the collect), 60-62 (mid
+    # 61: the gate, after its collect), 70-90 (mid 80: respond)
+    r = trace.reduce(tr)
+    gaps = dict(r["idle_gaps"])
+    assert gaps == pytest.approx({"lane.idle": 0.010, "lane.collect": 0.010,
+                                  "lane.gate": 0.002, "lane.respond": 0.020})
 
 
 def test_trace_reduce_recorded():
@@ -169,28 +198,42 @@ def test_filter_set_agrees_with_definition():
         assert got == want, t
 
 
-# -- windowed histogram delta ------------------------------------------------------
+# -- windowed histogram delta, the lane segment readers ---------------------
 
 def test_hist_delta_counts_only_the_window():
-    start = {"counts": np.zeros(64, np.int64), "count": 0, "sum_ns": 0}
-    start["counts"][40] = 1000          # warm-up: ~1 ms observations
-    start["count"] = 1000
-    end = copy.deepcopy(start)
-    end["counts"][30] += 95             # window: ~32-46 us
-    end["counts"][33] += 5              # window tail: ~92-131 us
-    end["count"] += 100
+    start = {"count": 1000, "sum_ns": 1000 * 1_000_000}     # the set-up's
+    end = {"count": 1100, "sum_ns": start["sum_ns"] + 100 * 40_000 + 7}
     d = stats.hist_delta(start, end)
-    assert d["count"] == 100 and int(d["counts"].sum()) == 100
-    p50 = stats.hist_percentile(d, 0.5)
-    assert stats.HIST_EDGES_NS[29] <= p50 <= stats.HIST_EDGES_NS[30]
-    p99 = stats.hist_percentile(d, 0.99)
-    assert stats.HIST_EDGES_NS[32] <= p99 <= stats.HIST_EDGES_NS[33]
-    assert stats.hist_percentile(stats.hist_delta(start, start), 0.5) is None
+    assert d == {"count": 100, "sum_ns": 100 * 40_000 + 7}
+    assert stats.hist_delta(start, start) == {"count": 0, "sum_ns": 0}
 
 
-def test_hist_edges_match_the_broker():
-    from emqx_tpu.observe.metrics import HIST_EDGES_NS
-    assert tuple(stats.HIST_EDGES_NS) == tuple(HIST_EDGES_NS)
+@pytest.mark.parametrize("name", sorted(readers.LANE_SEGMENTS))
+def test_lane_segment_reader(name):
+    """Each segment's reader is the exact mean of its own histogram's
+    window delta, in ms, and None where the window observed nothing."""
+    read = registry.metric_reader(name.replace("_mean_ms",
+                                               "_mean_ms.open"))
+    empty = {h: {"count": 0, "sum_ns": 0} for h in readers.LANE_HISTS}
+    assert read({"lane_hists": empty}) is None
+    hists = {h: {"count": 3 + i, "sum_ns": 1_000_003 * (i + 1)}
+             for i, h in enumerate(readers.LANE_HISTS)}
+    i = readers.LANE_HISTS.index(readers.LANE_SEGMENTS[name])
+    assert read({"lane_hists": hists}) == 1_000_003 * (i + 1) / (3 + i) / 1e6
+
+
+def test_segments_line_sums_the_six():
+    ms = 1_000_000
+    hists = {h: {"count": 4, "sum_ns": 4 * (i + 1) * ms}
+             for i, h in enumerate(readers.LANE_SEGMENTS.values())}
+    hists[readers.LANE_TOTAL] = {"count": 4, "sum_ns": 4 * 21 * ms}
+    line = evaluate.segments_line(hists)
+    assert "host_park 1.0 (4)" in line and "host_deliver 6.0 (4)" in line
+    assert "sum 21.0;" in line and line.endswith("lane_total 21.0 (4)")
+    hists[readers.LANE_SEGMENTS["lane_wait_mean_ms"]] = {"count": 0,
+                                                         "sum_ns": 0}
+    assert "lane_wait None (0)" in evaluate.segments_line(hists)
+    assert "sum None;" in evaluate.segments_line(hists)
 
 
 # -- finding parts by name ---------------------------------------------------------
@@ -254,11 +297,11 @@ def _run(workload: str, fault=None, seconds=2.0):
                       layout=layout, chip=False, fault=fault)
     res, lines, checks = evaluate.evaluate(cell, out, seconds, False,
                                            jax.devices()[0])
-    return cell, res, lines
+    return cell, res, lines, out
 
 
 def test_result_line_schema_and_correct_run():
-    cell, res, lines = _run("p2p_1m.knee80")
+    cell, res, lines, _ = _run("p2p_1m.knee80")
     line = json.loads(json.dumps(res))
     assert list(line)[-1] == "checks"
     for k in ("correct", "attempted", "failed", "metrics", "device"):
@@ -277,6 +320,81 @@ def test_result_line_schema_and_correct_run():
     assert any(ln.startswith("device lane served the window: True")
                for ln in lines), lines
     assert any(ln.startswith("stalls in the window:") for ln in lines)
+    assert any(ln.startswith("lane segments, mean ms per message")
+               for ln in lines)
+
+
+def test_warm_window_is_outside_the_measurement():
+    """The warm window sends the cell's traffic before the measured one;
+    none of its publishes is in the measured population, the lane
+    counters or the lane histograms, and set-up ends at its first
+    scheduled send (``_run`` starts the process clock at 0)."""
+    _, res, lines, out = _run("p2p_1m.knee80")
+    (w,) = out["warm"]
+    assert w["sent"] >= 0.9 * harness.WARM_S * 300
+    assert not any(w["lane"].values()), w
+    assert w["t0"] + w["seconds"] * 1e9 <= out["t0"]
+    assert res["metrics"]["setup_s"]["value"] == w["t0"] / 1e9
+    n_pub = len(out["msg"])
+    assert res["attempted"] == n_pub > 0
+    assert not (out["msg"] & loadgen.WARM_BIT).any()
+    assert out["sched"].min() >= out["t0"]
+    s, e = out["start"], out["end"]
+    assert e["stats"]["lane_in"] - s["stats"]["lane_in"] == n_pub
+    assert {k: v["count"] for k, v in evaluate.lane_hists(out).items()} \
+        == dict.fromkeys(readers.LANE_HISTS, n_pub)
+    assert res["correct"] is True, res["checks"]
+
+
+class _WarmOnly(harness.Session):
+    """A session with nothing behind it: the warm window's own steps are
+    stand-ins, the lane's stale counter moves in the first warm window
+    only."""
+
+    def __init__(self):
+        self.cell = {"traffic": {"rate_per_s": 300}}
+        self.warm, self.pubs, self.reopened, self.calls = [], [], 0, 0
+        session = self
+
+        class Server:
+            _last_permit_flush = 0.0
+
+            def fast_stats(self):
+                session.calls += 1
+                stale = 1 if session.calls >= 2 else 0
+                return dict.fromkeys(harness.LANE_STATS, 0) | {
+                    "lane_stale": stale}
+
+        self.server = Server()
+
+    def _count(self, slot):
+        return 0
+
+    def _go(self, seconds, rate, warm):
+        assert warm
+        return 1_000 * (len(self.warm) + 1)
+
+    def _due(self, sent):
+        return [0] * 7, 0
+
+    def reopen_lane(self):
+        self.reopened += 1
+
+
+def test_warm_window_the_lane_did_not_serve_runs_again(monkeypatch):
+    """A host stall inside the warm window trips the lane: the lane
+    reopens and the warm window runs once more, so the measured window
+    does not spend the run's one void window on it; the permits' re-earn
+    clock restarts after the last warm window has drained."""
+    monkeypatch.setattr(harness, "_wait", lambda conns, what, timeout: [])
+    monkeypatch.setattr(harness.time, "sleep", lambda s: None)
+    s = _WarmOnly()
+    s.warm_window(5.0)
+    assert s.reopened == 1
+    first, second = s.warm
+    assert first["lane"]["lane_stale"] == 1
+    assert not any(second["lane"].values())
+    assert s.server._last_permit_flush > 0
 
 
 @pytest.mark.parametrize("fault", ["stall_once", "stall"])
@@ -288,9 +406,15 @@ def test_window_the_lane_did_not_serve(fault, monkeypatch):
     from emqx_tpu.broker import native_server as ns
 
     monkeypatch.setattr(ns, "LANE_STALE_BACKOFF_S", 1.0)
-    _, res, lines = _run("p2p_1m.knee80", fault=fault)
+    _, res, lines, out = _run("p2p_1m.knee80", fault=fault)
     assert any(ln.startswith("windows void before this one (lane "
                              "counters): 1") for ln in lines), lines
+    # the warm window runs again before the window that replaces the
+    # void one; set-up still ends at the first warm window's first send
+    first, second = out["warm"]
+    assert first["t0"] < second["t0"]
+    assert second["t0"] + second["seconds"] * 1e9 <= out["t0"]
+    assert res["metrics"]["setup_s"]["value"] == first["t0"] / 1e9
     assert res["checks"]["missing"]["value"] == 0
     if fault == "stall_once":
         assert res["correct"] is True, res["checks"]
@@ -305,7 +429,7 @@ def test_window_the_lane_did_not_serve(fault, monkeypatch):
 def test_broken_answers_fail_the_comparison(fault, seconds):
     # the control drops one lane message in a thousand: the window has
     # to carry more than a thousand publishes
-    _, res, _ = _run("p2p_1m.knee80", fault=fault, seconds=seconds)
+    _, res, _, _ = _run("p2p_1m.knee80", fault=fault, seconds=seconds)
     assert res["attempted"] > 1000 or fault != "control"
     assert res["correct"] is False
     assert res["checks"]["missing"]["value"] > 0

@@ -13,9 +13,12 @@ recorded trace. What ``reduce`` reads:
   is the step program's (``<module>`` or ``<module>(<id>)``);
 - the largest top-level device operations by time (an operation that
   runs inside a loop operation counts as part of the loop), and the
-  idle gaps between operations, each charged to the host span
-  (``lane.submit``, ``lane.collect``) in progress at its midpoint, else
-  ``no_span``.
+  idle gaps between operations, each charged to the innermost host span
+  in progress at its midpoint, else ``no_span``: the benchmark's own
+  ``lane.submit`` and ``lane.collect`` around the model's calls, and the
+  lane pump's phases ``lane.idle`` (no message to submit), ``lane.gate``
+  (the pipeline full, messages waiting on a collect) and
+  ``lane.respond`` (the answer handed to the C++ host).
 """
 
 from __future__ import annotations
@@ -27,7 +30,8 @@ import re
 import numpy as np
 
 WINDOW_SPAN = "bench.window"
-HOST_SPANS = ("lane.submit", "lane.collect")
+HOST_SPANS = ("lane.submit", "lane.collect", "lane.idle", "lane.gate",
+              "lane.respond")
 
 
 def short_name(name: str) -> str:
@@ -141,8 +145,9 @@ def reduce(tr: dict, step_module: str = None) -> dict:
         mid = (s + e) // 2
         label = "no_span"
         k = int(np.searchsorted(starts, mid, side="right")) - 1
-        # spans of one thread do not overlap; look back a few for another
-        # thread's span that still covers the midpoint
+        # the latest-starting span that covers the midpoint is the
+        # innermost (a collect nests in the pump's gate); look back a few
+        # for one that still covers it
         for j in range(k, max(-1, k - 8), -1):
             name, st, d = spans[j]
             if st <= mid < st + d:
